@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import io
+import os
 import sys
 import time
 from contextlib import contextmanager
@@ -12,6 +13,18 @@ from repro.core.costmodel import DEFAULT_HW, plan_cost
 from repro.core.scaling_plan import (Op, STRATEGIES, placement, plan_elastic,
                                      plan_elastic_min_move)
 from repro.core.topology import ElasticConfig, kv_cache_bytes, model_tensors
+
+
+def cpu_child_env(ndev: int = 8) -> Dict[str, str]:
+    """Environment for a benchmark's subprocess arm: ``ndev`` virtual CPU
+    devices, always on the CPU backend — on a machine with a TPU the child
+    must never try to take the chip this harness's process may hold."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    return env
+
 
 PAPER_MODELS = ["deepseek-v2-lite-16b", "qwen3-30b-a3b", "deepseek-v3"]
 TP_OF = {"deepseek-v2-lite-16b": 2, "qwen3-30b-a3b": 2, "deepseek-v3": 2}

@@ -6,11 +6,10 @@ Runs in a subprocess with 8 virtual host devices so the main process keeps
 the default single device.
 """
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, cpu_child_env
 
 CODE = r"""
 import json, time
@@ -61,9 +60,7 @@ print("JSON:" + json.dumps(dict(boot_s=round(boot_s, 3), rows=rows)))
 
 
 def run() -> Table:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = cpu_child_env()
     r = subprocess.run([sys.executable, "-c", CODE], env=env,
                        capture_output=True, text=True, timeout=900)
     if r.returncode != 0:

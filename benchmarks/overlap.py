@@ -24,11 +24,10 @@ Two tables:
   and modelled decode-stall seconds vs the serial sum (DESIGN.md §3).
 """
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import PAPER_MODELS, Table, scale_cost
+from benchmarks.common import PAPER_MODELS, Table, cpu_child_env, scale_cost
 
 CODE = r"""
 import json, time, sys
@@ -111,9 +110,7 @@ TRANSITIONS = [(4, 6), (6, 8)]
 
 
 def _run_mode(mode: str) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = cpu_child_env()
     r = subprocess.run([sys.executable, "-c", CODE, mode], env=env,
                        capture_output=True, text=True, timeout=900)
     if r.returncode != 0:

@@ -25,6 +25,9 @@ def main() -> None:
                     help="run a single benchmark by name (e.g. fig9, table2)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (ablation, bootup_breakdown, engine_measured,
                             expert_remap, expert_skew, fleet, granularity,
                             kv_pressure, latency_breakdown, memory_vs_ep,

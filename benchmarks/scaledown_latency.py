@@ -24,12 +24,11 @@ Two entry points (benchmarks/run.py registers both):
   in migrate mode, and a clean pool (``check_invariants``) after commit.
 """
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import (PAPER_MODELS, STRATEGY_LABELS, Table, feasible,
-                               scale_cost)
+from benchmarks.common import (PAPER_MODELS, STRATEGY_LABELS, Table,
+                               cpu_child_env, feasible, scale_cost)
 
 TRANSITIONS = {
     "deepseek-v2-lite-16b": [(8, 6), (6, 4), (4, 2)],
@@ -137,9 +136,7 @@ print("JSON:" + json.dumps(dict(
 
 
 def _run_mode(mode: str) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = cpu_child_env()
     r = subprocess.run([sys.executable, "-c", CODE, mode], env=env,
                        capture_output=True, text=True, timeout=900)
     if r.returncode != 0:

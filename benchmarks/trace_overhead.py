@@ -20,7 +20,7 @@ import subprocess
 import sys
 import tempfile
 
-from benchmarks.common import Table
+from benchmarks.common import Table, cpu_child_env
 
 CODE = r"""
 import json, time, sys
@@ -86,9 +86,7 @@ print("JSON:" + json.dumps(dict(
 
 
 def _run_mode(mode: str, trace_path: str | None = None) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = cpu_child_env()
     argv = [sys.executable, "-c", CODE, mode] + (
         [trace_path] if trace_path else [])
     r = subprocess.run(argv, env=env, capture_output=True, text=True,

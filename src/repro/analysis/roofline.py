@@ -11,8 +11,9 @@ collective bytes are parsed out of the optimized HLO text (sum of the output
 buffer sizes of all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute ops), since XLA's cost model does not expose them.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Per-chip peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``;
+a kind missing from the table raises rather than borrowing another chip's
+numbers.
 """
 from __future__ import annotations
 
@@ -20,9 +21,35 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: float      # HBM capacity
+    ici_bw: float         # bytes/s per ICI link
+    source: str
+
+
+#: keyed by ``device_kind`` as JAX reports it
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, hbm_bytes=16e9, ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip (taken "
+               "as 4 links of 50 GB/s)"),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       " add them to analysis.roofline.PEAKS with their "
+                       "source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -74,7 +101,12 @@ class Roofline:
     hbm_bytes: float             # total bytes accessed
     coll_bytes: Dict[str, int]   # per collective kind (global)
     chips: int
+    device_kind: str             # key into PEAKS
     model_flops: float = 0.0     # 6*N*D (analytic)
+
+    @property
+    def peaks(self) -> Peaks:
+        return peaks_for(self.device_kind)
 
     @property
     def total_coll_bytes(self) -> float:
@@ -82,15 +114,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
+        return self.flops / (self.chips * self.peaks.flops)
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.total_coll_bytes / (self.chips * ICI_BW)
+        return self.total_coll_bytes / (self.chips * self.peaks.ici_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -109,7 +141,8 @@ class Roofline:
             "flops": self.flops, "hbm_bytes": self.hbm_bytes,
             "coll_bytes": self.coll_bytes,
             "coll_bytes_total": self.total_coll_bytes,
-            "chips": self.chips, "model_flops": self.model_flops,
+            "chips": self.chips, "device_kind": self.device_kind,
+            "model_flops": self.model_flops,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
             "bottleneck": self.bottleneck,
@@ -117,7 +150,8 @@ class Roofline:
         }
 
 
-def from_compiled(compiled, chips: int, model_flops: float = 0.0,
+def from_compiled(compiled, chips: int, device_kind: str,
+                  model_flops: float = 0.0,
                   hlo_text: Optional[str] = None) -> Roofline:
     """Roofline terms from a compiled executable.
 
@@ -133,7 +167,7 @@ def from_compiled(compiled, chips: int, model_flops: float = 0.0,
     hbm = costs.bytes_accessed * chips
     coll = {k: int(v * chips) for k, v in costs.coll_bytes.items()}
     return Roofline(flops=flops, hbm_bytes=hbm, coll_bytes=coll, chips=chips,
-                    model_flops=model_flops)
+                    device_kind=device_kind, model_flops=model_flops)
 
 
 def model_flops_for(mcfg, shape, kind: str) -> float:

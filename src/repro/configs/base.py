@@ -79,6 +79,10 @@ class ModelConfig:
     max_seq_len: int = 131072
     tie_embeddings: bool = False
 
+    # ---- provenance ----
+    source: str = ""                  # where the published widths come from
+    omits: str = ""                   # published features the model lacks
+
     # -------------------------------------------------------------- derived
     @property
     def resolved_head_dim(self) -> int:

@@ -15,4 +15,6 @@ CONFIG = ModelConfig(
     num_experts=128,
     top_k=8,
     moe_d_ff=768,
+    source="arXiv:2505.09388",
+    omits="per-head QK RMSNorm (ROADMAP R3)",
 )

@@ -537,8 +537,11 @@ class HMM:
         t0 = time.perf_counter()
         assert cfg.tp == self.tp
         mesh = make_instance_mesh(cfg, self.all_devices)
-        params = init_params(self.mcfg, jax.random.PRNGKey(self.seed),
-                             jnp.dtype(self.mcfg.dtype))
+        # one executable: the random draws and their scaling fuse, so the
+        # default device holds the weights without per-op temporaries
+        params = jax.jit(init_params, static_argnums=(0, 2))(
+            self.mcfg, jax.random.PRNGKey(self.seed),
+            jnp.dtype(self.mcfg.dtype))
         if self.expert_mode == "pooled" and self.expert_pool_pages is None:
             # fixed for the HMM's lifetime: page indices and pool shapes
             # must agree across every later scale event

@@ -38,6 +38,8 @@ class StandbyInstance:
     mesh: Any
     compiled: Dict[str, Any]
     compile_s: float
+    # lower+compile seconds per executable (decode, chunk_prefill_<C>, ...)
+    compile_times: Dict[str, float] = dataclasses.field(default_factory=dict)
     activations: int = 0
 
 
@@ -101,7 +103,8 @@ class IMM:
             return self._cache[key]
         mesh = make_instance_mesh(cfg, self.hmm.all_devices)
         params_sds, cache_sds = self._shape_templates(cfg, mesh)
-        compiled, dt = compile_step_functions(
+        t0 = time.perf_counter()
+        compiled, times = compile_step_functions(
             self.mcfg, cfg, mesh, params_sds, cache_sds,
             batch_per_replica=self.batch_per_replica, max_len=self.max_len,
             prefill_buckets=self.prefill_buckets,
@@ -109,7 +112,8 @@ class IMM:
             kv_mode=self.hmm.kv_mode,
             kv_block_size=self.hmm.kv_block_size,
             collect_routing=self.collect_routing)
-        inst = StandbyInstance(cfg, mesh, compiled, dt)
+        dt = time.perf_counter() - t0
+        inst = StandbyInstance(cfg, mesh, compiled, dt, times)
         self._cache[key] = inst
         self.stats["compile_s_total"] += dt
         while len(self._cache) > self.lru_capacity:

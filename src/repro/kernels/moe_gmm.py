@@ -44,14 +44,14 @@ def _quant_kernel(table_ref, x_ref, pool_ref, scale_ref, o_ref):
     # int8 page with one f32 scale per (page, bank): the per-page dequant
     # commutes out of the contraction — x @ (w_i8 * s) = (x @ w_i8) * s —
     # so the MXU streams int8 weights at half the HBM bytes and one scalar
-    # multiply lands on the output tile.  scale tile selected by the SAME
-    # prefetched page table as the weight tile.
+    # multiply lands on the output tile.  scale_ref [E_local] in SMEM holds
+    # each local expert's page scale (gathered through the same table).
     x = x_ref[0].astype(jnp.float32)
     w = pool_ref[0].astype(jnp.float32)
     acc = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    o_ref[0] = (acc * scale_ref[0, 0]).astype(o_ref.dtype)
+    o_ref[0] = (acc * scale_ref[pl.program_id(0)]).astype(o_ref.dtype)
 
 
 def _clamp_block_f(dim: int, block: int) -> int:
@@ -122,6 +122,7 @@ def paged_gmm(table: jax.Array, pool: jax.Array, x: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((E_local, C_run, F), x.dtype),
         interpret=interpret,
+        name="paged_gmm",
     )(table, x, pool)
     return out[:, :C] if C_run != C else out
 
@@ -150,9 +151,9 @@ def quant_paged_gmm(table: jax.Array, pool: jax.Array, scales: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """Int8 variant of ``paged_gmm``: ``pool`` is int8 ``[n_pages, D, F]``
     and ``scales`` the per-page f32 dequant scales ``[n_pages]`` (one scalar
-    per page, ``kernels.quant.quantize_rows`` over ``(-2, -1)``).  The scale
-    BlockSpec dereferences the same prefetched page table as the weight
-    pages, so remapped pages always compute with their own scale.  Output in
+    per page, ``kernels.quant.quantize_rows`` over ``(-2, -1)``).  The
+    scales are gathered through the same page table as the weight pages,
+    so remapped pages always compute with their own scale.  Output in
     ``x.dtype``; oracle: ``ref.quant_paged_gmm_ref``."""
     E_local, C, D = x.shape
     n_pages, D2, F = pool.shape
@@ -163,7 +164,9 @@ def quant_paged_gmm(table: jax.Array, pool: jax.Array, scales: jax.Array,
         x = jnp.pad(x, ((0, 0), (0, C_pad - C), (0, 0)))
     bf = _clamp_block_f(F, block_f)
     C_run = x.shape[1]
-    scales2 = scales.astype(jnp.float32).reshape(n_pages, 1)
+    # one scale per local expert, read from SMEM: a (1, 1) block of an
+    # [n_pages, 1] array is not a Mosaic-legal block shape
+    expert_scales = scales.astype(jnp.float32)[table]
 
     grid = (E_local, C_run // bc, F // bf)
     out = pl.pallas_call(
@@ -175,15 +178,15 @@ def quant_paged_gmm(table: jax.Array, pool: jax.Array, scales: jax.Array,
                 pl.BlockSpec((1, bc, D), lambda e, i, j, tbl: (e, i, 0)),
                 pl.BlockSpec((1, D, bf),
                              lambda e, i, j, tbl: (tbl[e], 0, j)),
-                pl.BlockSpec((1, 1), lambda e, i, j, tbl: (tbl[e], 0),
-                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((1, bc, bf),
                                    lambda e, i, j, tbl: (e, i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((E_local, C_run, F), x.dtype),
         interpret=interpret,
-    )(table, x, pool, scales2)
+        name="quant_paged_gmm",
+    )(table, x, pool, expert_scales)
     return out[:, :C] if C_run != C else out
 
 

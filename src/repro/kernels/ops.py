@@ -1,8 +1,18 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (the kernel
-body executes in Python, validating logic + BlockSpecs); on a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile to Mosaic.
+Both choices below are made per call, from the backend JAX runs on, never
+at import:
+
+* ``interpret``: kernels run in the Pallas interpreter only on the CPU
+  (the kernel body executes in Python, validating logic + BlockSpecs); on
+  an accelerator they always compile to Mosaic.  A caller may still pass
+  ``interpret=`` explicitly.
+* ``impl`` (serving kernels): ``'auto'`` runs the jnp oracle from
+  ``kernels/ref.py`` on the CPU — interpret-mode Pallas inside the
+  per-layer decode scan is far slower than the gather, and the two are
+  parity-tested in test_kernels.py — and the kernel everywhere else.
+  ``'kernel'``/``'ref'`` force one side; ``REPRO_PAGED_IMPL`` (attention)
+  and ``REPRO_POOLED_IMPL`` (expert FFN) set the default.
 """
 from __future__ import annotations
 
@@ -16,8 +26,24 @@ from repro.kernels.moe_gmm import paged_gmm as _gmm
 from repro.kernels.paged_attention import paged_decode_attention as _paged
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0" or \
-    jax.default_backend() == "cpu"
+IMPL_ENV = ("REPRO_PAGED_IMPL", "REPRO_POOLED_IMPL")
+
+
+def on_cpu() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _interpret(kw) -> dict:
+    kw.setdefault("interpret", on_cpu())
+    return kw
+
+
+def _use_ref(kw, env: str) -> bool:
+    """Pop ``impl`` from ``kw`` and decide kernel vs oracle for this call."""
+    impl = kw.pop("impl", None) or os.environ.get(env, "auto")
+    if impl not in ("auto", "kernel", "ref"):
+        raise ValueError(f"{env}={impl!r}: expected auto, kernel or ref")
+    return impl == "ref" or (impl == "auto" and on_cpu())
 
 
 def _tuned(kernel, kw, keys):
@@ -34,160 +60,132 @@ def _tuned(kernel, kw, keys):
 
 
 def paged_gmm(table, pool, x, **kw):
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     _tuned("paged_gmm", kw, ("block_c", "block_f"))
     return _gmm(table, pool, x, **kw)
 
 
 def quant_paged_gmm(table, pool, scales, x, **kw):
-    """Int8 paged GMM (per-page f32 scales).  Same impl switch as
-    ``paged_expert_ffn``: kernel on accelerators, dequant-then-gather
-    reference on CPU (``REPRO_POOLED_IMPL`` / ``impl=`` override)."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_POOLED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    """Int8 paged GMM (per-page f32 scales); impl as ``paged_expert_ffn``."""
+    if _use_ref(kw, "REPRO_POOLED_IMPL"):
         from repro.kernels.ref import quant_paged_gmm_ref
         return quant_paged_gmm_ref(table, pool, scales, x)
     from repro.kernels.moe_gmm import quant_paged_gmm as _qgmm
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     _tuned("paged_gmm", kw, ("block_c", "block_f"))
     return _qgmm(table, pool, scales, x, **kw)
 
 
 def paged_expert_ffn(table_i, table_g, table_o, pool_i, pool_g, pool_o, x,
                      **kw):
-    """Paged SwiGLU expert FFN (the pooled-expert serving hot path).
-
-    ``impl='kernel'`` forces the Pallas paged-GMM kernel, ``'ref'`` the jnp
-    gather oracle; the default ``'auto'`` (overridable via
-    ``REPRO_POOLED_IMPL``) runs the kernel on accelerators and the reference
-    on CPU — interpret-mode Pallas inside the per-layer decode scan is far
-    slower than the gather, and the two are parity-tested in
-    test_kernels.py (same policy as ``block_paged_decode_attention``)."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_POOLED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    """Paged SwiGLU expert FFN (the pooled-expert serving hot path);
+    ``impl``/``REPRO_POOLED_IMPL`` as in the module docstring."""
+    if _use_ref(kw, "REPRO_POOLED_IMPL"):
         from repro.kernels.ref import paged_expert_ffn_ref
         return paged_expert_ffn_ref(table_i, table_g, table_o,
                                     pool_i, pool_g, pool_o, x)
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     _tuned("paged_expert_ffn", kw, ("block_c", "block_f"))
     return _ffn(table_i, table_g, table_o, pool_i, pool_g, pool_o, x, **kw)
 
 
 def quant_paged_expert_ffn(table_i, table_g, table_o, pool_i, pool_g, pool_o,
                            scale_i, scale_g, scale_o, x, **kw):
-    """Int8 paged SwiGLU FFN (per-page, per-bank f32 scales).  Same impl
-    switch / autotune consultation as ``paged_expert_ffn``."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_POOLED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    """Int8 paged SwiGLU FFN (per-page, per-bank f32 scales)."""
+    if _use_ref(kw, "REPRO_POOLED_IMPL"):
         from repro.kernels.ref import quant_paged_expert_ffn_ref
         return quant_paged_expert_ffn_ref(table_i, table_g, table_o,
                                           pool_i, pool_g, pool_o,
                                           scale_i, scale_g, scale_o, x)
     from repro.kernels.moe_gmm import quant_paged_expert_ffn as _qffn
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     _tuned("paged_expert_ffn", kw, ("block_c", "block_f"))
     return _qffn(table_i, table_g, table_o, pool_i, pool_g, pool_o,
                  scale_i, scale_g, scale_o, x, **kw)
 
 
 def flash_attention(q, k, v, **kw):
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     return _flash(q, k, v, **kw)
 
 
 def paged_decode_attention(q, k_cache, v_cache, lengths, **kw):
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     _tuned("paged_decode_attention", kw, ("block_k",))
     return _paged(q, k_cache, v_cache, lengths, **kw)
 
 
 def block_paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                                  **kw):
-    """Block-table paged decode (serving hot path; see kv_blocks.py).
-
-    ``impl='kernel'`` forces the Pallas kernel, ``'ref'`` the jnp gather
-    oracle; the default ``'auto'`` (overridable via ``REPRO_PAGED_IMPL``)
-    runs the kernel on accelerators and falls back to the reference on CPU —
-    interpret-mode Pallas inside the per-layer decode scan is far slower
-    than the gather, and the two are parity-tested in test_kernels.py.
-    """
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_PAGED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    """Block-table paged decode (serving hot path; see kv_blocks.py);
+    ``impl``/``REPRO_PAGED_IMPL`` as in the module docstring.  On a mesh,
+    ``models.layers.paged_attend`` runs it once per KV partition."""
+    if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import block_paged_decode_attention_ref
         return block_paged_decode_attention_ref(q, k_pool, v_pool,
                                                 block_tables, lengths)
     from repro.kernels.paged_attention import \
         block_paged_decode_attention as _block_paged
-    kw.setdefault("interpret", _INTERPRET)
-    return _block_paged(q, k_pool, v_pool, block_tables, lengths, **kw)
+    return _block_paged(q, k_pool, v_pool, block_tables, lengths,
+                        **_interpret(kw))
 
 
 def quant_block_paged_decode_attention(q, k_pool, k_scale, v_pool, v_scale,
                                        block_tables, lengths, **kw):
     """Int8 block-table paged decode (per-token f32 scale pools riding the
-    block table).  Same impl switch as ``block_paged_decode_attention``."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_PAGED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    block table)."""
+    if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import quant_block_paged_decode_attention_ref
         return quant_block_paged_decode_attention_ref(
             q, k_pool, k_scale, v_pool, v_scale, block_tables, lengths)
     from repro.kernels.paged_attention import \
         quant_block_paged_decode_attention as _qblock
-    kw.setdefault("interpret", _INTERPRET)
-    return _qblock(q, k_pool, k_scale, v_pool, v_scale, block_tables,
-                   lengths, **kw)
+    return _qblock(q, k_pool, k_scale, v_pool, v_scale, block_tables, lengths,
+                   **_interpret(kw))
 
 
 def mixed_block_paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                                 q_lens, **kw):
     """Mixed chunked-prefill / decode attention over the block pool (the
-    continuous-batching hot path; see serving/scheduler.py).
-
-    Same impl switch as ``block_paged_decode_attention``: ``impl='kernel'``
-    forces the Pallas kernel, ``'ref'`` the jnp gather oracle, and the
-    default ``'auto'`` (overridable via ``REPRO_PAGED_IMPL``) picks the
-    kernel on accelerators and the reference on CPU; the two are
-    parity-tested in test_kernels.py."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_PAGED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+    continuous-batching hot path; see serving/scheduler.py); arguments as
+    ``block_paged_decode_attention``."""
+    if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import mixed_block_paged_attention_ref
         return mixed_block_paged_attention_ref(q, k_pool, v_pool,
                                                block_tables, ctx_lens, q_lens)
     from repro.kernels.paged_attention import \
         mixed_block_paged_attention as _mixed
-    kw.setdefault("interpret", _INTERPRET)
-    return _mixed(q, k_pool, v_pool, block_tables, ctx_lens, q_lens, **kw)
+    return _mixed(q, k_pool, v_pool, block_tables, ctx_lens, q_lens,
+                  **_interpret(kw))
 
 
 def quant_mixed_block_paged_attention(q, k_pool, k_scale, v_pool, v_scale,
-                                      block_tables, ctx_lens, q_lens, **kw):
-    """Int8 mixed chunked-prefill / decode attention.  Same impl switch as
-    ``mixed_block_paged_attention``."""
-    impl = kw.pop("impl", None) or os.environ.get("REPRO_PAGED_IMPL", "auto")
-    if impl == "ref" or (impl == "auto" and jax.default_backend() == "cpu"):
+                                      block_tables, ctx_lens, q_lens,
+                                      **kw):
+    """Int8 mixed chunked-prefill / decode attention."""
+    if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import quant_mixed_block_paged_attention_ref
         return quant_mixed_block_paged_attention_ref(
             q, k_pool, k_scale, v_pool, v_scale, block_tables, ctx_lens,
             q_lens)
     from repro.kernels.paged_attention import \
         quant_mixed_block_paged_attention as _qmixed
-    kw.setdefault("interpret", _INTERPRET)
     return _qmixed(q, k_pool, k_scale, v_pool, v_scale, block_tables,
-                   ctx_lens, q_lens, **kw)
+                   ctx_lens, q_lens, **_interpret(kw))
 
 
 def ssd_scan(x, dt, A, Bm, Cm, **kw):
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     return _ssd(x, dt, A, Bm, Cm, **kw)
 
 
 def mla_decode_attention(q_eff, q_rope, c_cache, kr_cache, lengths, **kw):
     from repro.kernels.mla_decode import mla_decode_attention as _mla
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     return _mla(q_eff, q_rope, c_cache, kr_cache, lengths, **kw)
 
 
 def kv_cache_write(cache, new_kv, pos, **kw):
     from repro.kernels.kv_write import kv_cache_write as _kvw
-    kw.setdefault("interpret", _INTERPRET)
+    _interpret(kw)
     return _kvw(cache, new_kv, pos, **kw)

@@ -1,23 +1,31 @@
-"""Decode attention over the KV cache (Pallas TPU) — two layouts.
+"""Attention over the block-paged KV pool (Pallas TPU) — one kernel family.
 
-``paged_decode_attention``: slot-contiguous cache ``[B, S_max, KVH, hd]``
-(one dense row per sequence).  One query token per sequence, masked by
-per-sequence valid length.  Lengths arrive via scalar prefetch so the kernel
-skips kv tiles entirely beyond a sequence's length — on real hardware this
-is the difference between O(S_max) and O(len) HBM traffic per step.
+The PagedAttention layout: one shared block *pool* ``[NB, bs, KVH, hd]``
+plus per-sequence block tables ``[B, MB]`` (``serving/kv_blocks.py``).  The
+block table rides the scalar prefetch: each kv BlockSpec index_map
+dereferences ``table[b, ki]`` to pick the physical block, so the kernel
+reads exactly the blocks a sequence owns — non-contiguous pool rows appear
+contiguous to the softmax, the kernel-level zero-copy-remap guarantee
+(permuting pool rows + tables is a no-op, asserted in tests).
 
-``block_paged_decode_attention``: the PagedAttention layout — one shared
-block *pool* ``[NB, bs, KVH, hd]`` plus per-sequence block tables
-``[B, MB]`` (``serving/kv_blocks.py``).  The block table rides the scalar
-prefetch too: each kv BlockSpec index_map dereferences ``table[b, ki]`` to
-pick the physical block, so the kernel reads exactly the blocks a sequence
-owns — non-contiguous pool rows appear contiguous to the softmax, the
-kernel-level zero-copy-remap guarantee (permuting pool rows + tables is a
-no-op, asserted in tests).
+One kernel serves every query shape: query row ``i`` of sequence ``b``
+attends causally from absolute position ``ctx[b] - q_len[b] + i``.  A
+chunked-prefill chunk is ``q_len`` rows; decode is ``Sq == q_len == 1``
+(``block_paged_decode_attention``); the slot-contiguous cache
+``[B, S_max, KVH, hd]`` is the same pool viewed with an identity table
+(``paged_decode_attention``).  int8 pools fuse the per-token dequant.
 
-Grid (B, KVH, n_k); q block [1, 1, G, hd] (the G=H/KVH grouped query heads
-of one kv head), kv blocks [1, bk, 1, hd] (dense) / [1, bs, 1, hd] (paged);
-online softmax in VMEM scratch.
+Grid ``(B, Sq/bq, MB)``.  Each step streams one whole KV block with all
+``KVH`` heads — ``(1, bs, KVH, hd)`` — because Mosaic requires the last two
+block dims to be full or (8, 128)-aligned, which a one-head ``(…, 1, hd)``
+block is not.  The query tile ``(1, bq, KVH, G, hd)`` holds every head too;
+the body loops over heads with an online softmax in VMEM scratch.  Blocks
+beyond a sequence's context, and blocks past the query tile's last causal
+position, are skipped: their grid steps repeat the last live block's index,
+so Pallas issues no fetch, and the body computes nothing.  A decode row
+thus reads ``ceil(len/bs)`` blocks, not ``MB``.  The query tiles of one
+prefill chunk each stream the context up to their own causal frontier, so
+a chunk of ``n`` tiles reads the shared prefix ``n`` times.
 """
 from __future__ import annotations
 
@@ -30,251 +38,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# query rows of a prefill chunk per grid step: 32 rows x 8 grouped heads
+# fill 256 MXU rows per KV head at qwen3-30b-a3b's 32/4 heads
+CHUNK_TILE = 32
 
 
-def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, scale, block_k, n_k):
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = lengths_ref[b]
-
-    @pl.when(ki * block_k < length)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_cur[:, None])
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
-
-    @pl.when(ki == n_k - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def paged_decode_attention(q: jax.Array, k_cache: jax.Array,
-                           v_cache: jax.Array, lengths: jax.Array, *,
-                           block_k: int = 128,
-                           interpret: bool = False) -> jax.Array:
-    """q [B,H,hd]; k/v_cache [B,S_max,KVH,hd]; lengths [B] -> [B,H,hd]."""
-    B, H, hd = q.shape
-    S_max, KVH = k_cache.shape[1], k_cache.shape[2]
-    G = H // KVH
-    bk = min(block_k, S_max)
-    assert S_max % bk == 0
-    n_k = S_max // bk
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KVH, G, hd)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_k=bk, n_k=n_k),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, KVH, n_k),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, h, ki, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, bk, 1, hd), lambda b, h, ki, L: (b, ki, h, 0)),
-                pl.BlockSpec((1, bk, 1, hd), lambda b, h, ki, L: (b, ki, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, h, ki, L: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
-    return out.reshape(B, H, hd)
-
-
-def _block_kernel(lengths_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_k, n_k):
-    # block table is consumed by the BlockSpec index maps; the compute body
-    # is identical to the slot-contiguous kernel
-    del bt_ref
-    _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            scale=scale, block_k=block_k, n_k=n_k)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def block_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                                 v_pool: jax.Array, block_tables: jax.Array,
-                                 lengths: jax.Array, *,
-                                 interpret: bool = False) -> jax.Array:
-    """q [B,H,hd]; k/v_pool [NB,bs,KVH,hd]; block_tables [B,MB] int32;
-    lengths [B] -> [B,H,hd].  kv tile ``ki`` of sequence ``b`` is pool row
-    ``block_tables[b, ki]`` — dereferenced in the BlockSpec index_map via
-    scalar prefetch, so only owned blocks are streamed from HBM (and none at
-    all beyond ``lengths[b]``)."""
-    B, H, hd = q.shape
-    bs, KVH = k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    G = H // KVH
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KVH, G, hd)
-
-    out = pl.pallas_call(
-        functools.partial(_block_kernel, scale=scale, block_k=bs, n_k=MB),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KVH, MB),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, h, ki, L, BT: (b, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, h, ki, L, BT: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        interpret=interpret,
-    )(lengths, block_tables.astype(jnp.int32), qg, k_pool, v_pool)
-    return out.reshape(B, H, hd)
-
-
-def _quant_block_kernel(lengths_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
-                        vs_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
-                        block_k, n_k):
-    # int8 pools + [NB, bs] f32 scale pools: the scale tiles ride the SAME
-    # block-table dereference as the entry tiles (scalar-prefetch path), so
-    # a remapped/migrated block always arrives with its own scales.
-    del bt_ref
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = lengths_ref[b]
-
-    @pl.when(ki * block_k < length)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, hd] int8
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sk = ks_ref[0, :]                                    # [bs] f32
-        sv = vs_ref[0, :]
-        # per-token k-dequant commutes out of the q.k^T contraction:
-        # column-scale the scores instead of materializing a dequant tile
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
-            * sk[None, :] * scale
-        pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_cur[:, None])
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        # v-dequant folds into the probability rows the same way
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p * sv[None, :], v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
-
-    @pl.when(ki == n_k - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def quant_block_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                                       k_scale: jax.Array,
-                                       v_pool: jax.Array,
-                                       v_scale: jax.Array,
-                                       block_tables: jax.Array,
-                                       lengths: jax.Array, *,
-                                       interpret: bool = False) -> jax.Array:
-    """Int8 variant of ``block_paged_decode_attention``: k/v_pool are int8
-    ``[NB, bs, KVH, hd]`` and k/v_scale the per-token f32 scale pools
-    ``[NB, bs]`` (``kernels.quant.quantize_rows`` over ``(KVH, hd)``).
-    The scale BlockSpecs dereference the same prefetched block table as the
-    entry pools, so dequant is fused into the softmax at ~half the HBM
-    traffic of the f32 path.  Oracle:
-    ``ref.quant_block_paged_decode_attention_ref``."""
-    B, H, hd = q.shape
-    bs, KVH = k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    G = H // KVH
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, KVH, G, hd)
-
-    out = pl.pallas_call(
-        functools.partial(_quant_block_kernel, scale=scale, block_k=bs,
-                          n_k=MB),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KVH, MB),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, h, ki, L, BT: (b, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs),
-                             lambda b, h, ki, L, BT: (BT[b, ki], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, h, ki, L, BT: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        interpret=interpret,
-    )(lengths, block_tables.astype(jnp.int32), qg,
-      k_pool, k_scale.astype(jnp.float32),
-      v_pool, v_scale.astype(jnp.float32))
-    return out.reshape(B, H, hd)
-
-
-def _mixed_kernel(ctx_ref, qlen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_k, n_k, G):
+def _kernel(ctx_ref, qlen_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+            scale, bs, n_k, bq, G, KVH, quant):
     # block table is consumed by the BlockSpec index maps
     del bt_ref
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    qt = pl.program_id(1)
     ki = pl.program_id(2)
+    R = bq * G                                   # query rows per kv head
 
     @pl.when(ki == 0)
     def _init():
@@ -283,39 +63,104 @@ def _mixed_kernel(ctx_ref, qlen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[b]
-    q_len = qlen_ref[b]
+    # absolute position of this tile's first query row; padding rows
+    # (i >= q_len) degrade to full-context decode masking so every row keeps
+    # a sane softmax (block 0 is always live: ctx >= 1)
+    q0 = ctx - qlen_ref[b] + qt * bq
 
-    @pl.when(ki * block_k < ctx)
+    @pl.when((ki * bs < ctx) & (ki * bs <= q0 + bq - 1))
     def _step():
-        q3 = q_ref[0, 0].astype(jnp.float32)                 # [Sq, G, hd]
-        sq = q3.shape[0]
-        q2 = q3.reshape(sq * G, q3.shape[2])                 # [Sq*G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
-        # query row qi sits at absolute position ctx - q_len + qi; padding
-        # rows (qi >= q_len) degrade to full-context decode masking so every
-        # row keeps a sane softmax (block 0 is always live: ctx >= 1)
-        q_abs = ctx - q_len + qi
-        s = jnp.where((pos < ctx) & (pos <= q_abs), s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_cur[:, None])
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
+        pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
+        q_abs = q0 + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0) // G
+        valid = (pos < ctx) & (pos <= q_abs)
+        if quant:
+            sk = ks_ref[0]                                   # [1, bs] f32
+            sv = vs_ref[0]
+        for h in range(KVH):
+            rows = pl.ds(h * R, R)
+            q = q_ref[0, :, h].astype(jnp.float32).reshape(R, -1)
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # [bs, hd]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            # per-token k-dequant commutes out of the q.k^T contraction:
+            # column-scale the scores instead of materializing a dequant tile
+            s = (s * sk if quant else s) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[rows]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_cur)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+            # v-dequant folds into the probability rows the same way
+            pv = p * sv if quant else p
+            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                pv, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[rows] = m_cur
 
     @pl.when(ki == n_k - 1)
     def _done():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = o.reshape(o_ref.shape[2], G,
-                                o.shape[-1]).astype(o_ref.dtype)
+        for h in range(KVH):
+            rows = pl.ds(h * R, R)
+            o = acc_ref[rows] / jnp.maximum(l_ref[rows], 1e-30)
+            o_ref[0, :, h] = o.reshape(bq, G, -1).astype(o_ref.dtype)
+
+
+def _paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_lens,
+                     k_scale=None, v_scale=None, *, block_q, interpret):
+    """q [B,Sq,H,hd]; pools [NB,bs,KVH,hd] (+ [NB,bs] f32 scales when
+    int8) -> [B,Sq,H,hd]."""
+    B, Sq, H, hd = q.shape
+    NB, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    MB = block_tables.shape[1]
+    G = H // KVH
+    bq = block_q if Sq % block_q == 0 else Sq
+    quant = k_scale is not None
+    bt = jnp.minimum(block_tables.astype(jnp.int32), NB - 1)
+    qg = q.reshape(B, Sq, KVH, G, hd)
+
+    q_spec = pl.BlockSpec((1, bq, KVH, G, hd),
+                          lambda b, t, ki, C, Q, BT: (b, t, 0, 0, 0))
+
+    def kv_block(b, t, ki, C, Q, BT):
+        # clamp to the tile's last live block (the kernel body's condition)
+        last = jnp.minimum(C[b] - 1, C[b] - Q[b] + (t + 1) * bq - 1) // bs
+        return BT[b, jnp.minimum(ki, last)]
+
+    kv_spec = pl.BlockSpec((1, bs, KVH, hd),
+                           lambda *g: (kv_block(*g), 0, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [qg, k_pool, v_pool]
+    if quant:
+        # [NB, 1, bs] so the scale block's last two dims are full dims; the
+        # scales dereference the same prefetched table as the entries, so a
+        # remapped/migrated block always arrives with its own scales
+        s_spec = pl.BlockSpec((1, 1, bs), lambda *g: (kv_block(*g), 0, 0))
+        in_specs += [s_spec, s_spec]
+        operands += [k_scale.astype(jnp.float32).reshape(NB, 1, bs),
+                     v_scale.astype(jnp.float32).reshape(NB, 1, bs)]
+    R = bq * G
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=1.0 / math.sqrt(hd), bs=bs, n_k=MB,
+                          bq=bq, G=G, KVH=KVH, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, Sq // bq, MB),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((KVH * R, 1), jnp.float32),
+                pltpu.VMEM((KVH * R, 1), jnp.float32),
+                pltpu.VMEM((KVH * R, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        interpret=interpret,
+        name="paged_attention",
+    )(ctx_lens.astype(jnp.int32), q_lens.astype(jnp.int32), bt, *operands)
+    return out.reshape(B, Sq, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -334,96 +179,11 @@ def mixed_block_paged_attention(q: jax.Array, k_pool: jax.Array,
     paged decode, so one kernel serves interleaved prefill+decode buckets.
     Sentinel (``NB``) block-table rows are clamped in-bounds before the
     index_map dereference and position-masked inert; blocks at or beyond
-    ``ctx_lens[b]`` are skipped entirely.  Oracle:
+    ``ctx_lens[b]`` are neither fetched nor computed.  Oracle:
     ``ref.mixed_block_paged_attention_ref``.
     """
-    B, Sq, H, hd = q.shape
-    NB, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    G = H // KVH
-    scale = 1.0 / math.sqrt(hd)
-    bt = jnp.minimum(block_tables.astype(jnp.int32), NB - 1)
-    qg = q.reshape(B, Sq, KVH, G, hd).transpose(0, 2, 1, 3, 4)
-
-    out = pl.pallas_call(
-        functools.partial(_mixed_kernel, scale=scale, block_k=bs, n_k=MB,
-                          G=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, KVH, MB),
-            in_specs=[
-                pl.BlockSpec((1, 1, Sq, G, hd),
-                             lambda b, h, ki, C, Q, BT: (b, h, 0, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, Sq, G, hd),
-                                   lambda b, h, ki, C, Q, BT:
-                                   (b, h, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((Sq * G,), jnp.float32),
-                pltpu.VMEM((Sq * G,), jnp.float32),
-                pltpu.VMEM((Sq * G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, Sq, G, hd), q.dtype),
-        interpret=interpret,
-    )(ctx_lens.astype(jnp.int32), q_lens.astype(jnp.int32), bt,
-      qg, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, Sq, H, hd)
-
-
-def _quant_mixed_kernel(ctx_ref, qlen_ref, bt_ref, q_ref, k_ref, ks_ref,
-                        v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        scale, block_k, n_k, G):
-    del bt_ref
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ctx = ctx_ref[b]
-    q_len = qlen_ref[b]
-
-    @pl.when(ki * block_k < ctx)
-    def _step():
-        q3 = q_ref[0, 0].astype(jnp.float32)                 # [Sq, G, hd]
-        sq = q3.shape[0]
-        q2 = q3.reshape(sq * G, q3.shape[2])                 # [Sq*G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bs, hd] int8
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sk = ks_ref[0, :]                                    # [bs] f32
-        sv = vs_ref[0, :]
-        # same commuting dequant as _quant_block_kernel: column-scale scores
-        # by sk, row-scale probabilities by sv
-        s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) \
-            * sk[None, :] * scale
-        pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
-        q_abs = ctx - q_len + qi
-        s = jnp.where((pos < ctx) & (pos <= q_abs), s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_cur[:, None])
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p * sv[None, :], v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_cur
-
-    @pl.when(ki == n_k - 1)
-    def _done():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = o.reshape(o_ref.shape[2], G,
-                                o.shape[-1]).astype(o_ref.dtype)
+    return _paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                            q_lens, block_q=CHUNK_TILE, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -435,47 +195,63 @@ def quant_mixed_block_paged_attention(q: jax.Array, k_pool: jax.Array,
                                       q_lens: jax.Array, *,
                                       interpret: bool = False) -> jax.Array:
     """Int8 variant of ``mixed_block_paged_attention``: same masks and mixed
-    prefill/decode semantics, int8 k/v pools with [NB, bs] f32 scale pools
-    riding the prefetched block table.  Oracle:
+    prefill/decode semantics, int8 k/v pools with [NB, bs] f32 per-token
+    scale pools riding the prefetched block table.  Oracle:
     ``ref.quant_mixed_block_paged_attention_ref``."""
-    B, Sq, H, hd = q.shape
-    NB, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    G = H // KVH
-    scale = 1.0 / math.sqrt(hd)
-    bt = jnp.minimum(block_tables.astype(jnp.int32), NB - 1)
-    qg = q.reshape(B, Sq, KVH, G, hd).transpose(0, 2, 1, 3, 4)
+    return _paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                            q_lens, k_scale, v_scale, block_q=CHUNK_TILE,
+                            interpret=interpret)
 
-    out = pl.pallas_call(
-        functools.partial(_quant_mixed_kernel, scale=scale, block_k=bs,
-                          n_k=MB, G=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, KVH, MB),
-            in_specs=[
-                pl.BlockSpec((1, 1, Sq, G, hd),
-                             lambda b, h, ki, C, Q, BT: (b, h, 0, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0, h, 0)),
-                pl.BlockSpec((1, bs),
-                             lambda b, h, ki, C, Q, BT: (BT[b, ki], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, Sq, G, hd),
-                                   lambda b, h, ki, C, Q, BT:
-                                   (b, h, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((Sq * G,), jnp.float32),
-                pltpu.VMEM((Sq * G,), jnp.float32),
-                pltpu.VMEM((Sq * G, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, Sq, G, hd), q.dtype),
-        interpret=interpret,
-    )(ctx_lens.astype(jnp.int32), q_lens.astype(jnp.int32), bt,
-      qg, k_pool, k_scale.astype(jnp.float32),
-      v_pool, v_scale.astype(jnp.float32))
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, Sq, H, hd)
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def block_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                                 v_pool: jax.Array, block_tables: jax.Array,
+                                 lengths: jax.Array, *,
+                                 interpret: bool = False) -> jax.Array:
+    """q [B,H,hd]; k/v_pool [NB,bs,KVH,hd]; block_tables [B,MB] int32;
+    lengths [B] -> [B,H,hd].  kv tile ``ki`` of sequence ``b`` is pool row
+    ``block_tables[b, ki]`` — dereferenced in the BlockSpec index_map via
+    scalar prefetch, so only owned blocks are streamed from HBM (and none at
+    all beyond ``lengths[b]``).  The mixed kernel at one query row."""
+    ones = jnp.ones_like(lengths)
+    return _paged_attention(q[:, None], k_pool, v_pool, block_tables,
+                            lengths, ones, block_q=1,
+                            interpret=interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quant_block_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                                       k_scale: jax.Array,
+                                       v_pool: jax.Array,
+                                       v_scale: jax.Array,
+                                       block_tables: jax.Array,
+                                       lengths: jax.Array, *,
+                                       interpret: bool = False) -> jax.Array:
+    """Int8 variant of ``block_paged_decode_attention``: k/v_pool are int8
+    ``[NB, bs, KVH, hd]`` and k/v_scale the per-token f32 scale pools
+    ``[NB, bs]`` (``kernels.quant.quantize_rows`` over ``(KVH, hd)``).
+    Dequant is fused into the softmax at ~half the HBM traffic of the f32
+    path.  Oracle: ``ref.quant_block_paged_decode_attention_ref``."""
+    ones = jnp.ones_like(lengths)
+    return _paged_attention(q[:, None], k_pool, v_pool, block_tables,
+                            lengths, ones, k_scale, v_scale, block_q=1,
+                            interpret=interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def paged_decode_attention(q: jax.Array, k_cache: jax.Array,
+                           v_cache: jax.Array, lengths: jax.Array, *,
+                           block_k: int = 128,
+                           interpret: bool = False) -> jax.Array:
+    """Slot-contiguous cache: q [B,H,hd]; k/v_cache [B,S_max,KVH,hd];
+    lengths [B] -> [B,H,hd].  The cache is viewed as a pool of
+    ``block_k``-token blocks with an identity block table."""
+    B, S_max, KVH, hd = k_cache.shape
+    bk = min(block_k, S_max)
+    assert S_max % bk == 0
+    n_k = S_max // bk
+    bt = jnp.arange(B * n_k, dtype=jnp.int32).reshape(B, n_k)
+    as_pool = lambda c: c.reshape(B * n_k, bk, KVH, hd)
+    return block_paged_decode_attention(q, as_pool(k_cache),
+                                        as_pool(v_cache), bt, lengths,
+                                        interpret=interpret)

@@ -43,6 +43,9 @@ OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 # sliding-window length used to run long_500k on full-attention archs
 LONG_CONTEXT_WINDOW = 8192
 
+#: the chip the production meshes (launch/mesh.py) model
+POD_DEVICE_KIND = "TPU v5 lite"
+
 
 def plan_for(mcfg: ModelConfig, shape: InputShape):
     """Returns (effective_cfg, kind) or (None, skip_reason)."""
@@ -231,7 +234,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
                 if v is not None:
                     mem_rec[attr] = int(v)
         hlo = compiled.as_text()
-        rl = from_compiled(compiled, chips,
+        # placeholder CPU devices stand in for a v5e pod: name its peaks
+        rl = from_compiled(compiled, chips, POD_DEVICE_KIND,
                            model_flops_for(mcfg, shape,
                                            "train" if "train" in kind else
                                            ("decode" if kind == "decode"

@@ -205,8 +205,61 @@ def attention_apply(cfg, p, x, positions, *, cache=None, write_pos=None,
     return linear(p["o"], y.reshape(B, Sq, H * hd)), new_cache
 
 
+def paged_attend(parallel, attend, q, pools, table, ctx_lens, *rest):
+    """``attend(q, *pools, table, ctx_lens, *rest)`` over the paged pool.
+
+    ``pools``: the KV pool leaves, block axis first; ``table`` [B,MB]
+    global block ids, ``NB`` marking padding.  Without a multi-device mesh
+    the table is clamped in-bounds and ``attend`` runs once.  A Mosaic
+    kernel cannot be partitioned automatically, so on a mesh ``attend``
+    runs once per KV partition: the pool's block axis is sharded over the
+    data-parallel axes of ``parallel``, one partition per replica, and
+    every block of a sequence lives in its replica's partition
+    (serving/kv_blocks.py; migration re-homes blocks before their slot
+    moves).  Inside the ``shard_map`` the table is rebased onto the local
+    partition.  A batch that divides over the replicas is split across
+    them, row ``b`` to replica ``b // (B / replicas)``; otherwise (one
+    prefill chunk) every replica runs the whole batch against its own
+    partition and the replica owning a row's first block keeps that row —
+    the others' work is discarded.  A row with a live block outside its
+    home partition comes out NaN, never a plausible wrong answer (a
+    raising host callback would leave the other replicas waiting in the
+    collective).
+    """
+    from jax.sharding import PartitionSpec as P
+
+    nb = pools[0].shape[0]
+    mesh = getattr(parallel, "mesh", None)
+    if mesh is None or mesh.size == 1:
+        return attend(q, *pools, jnp.minimum(table, nb - 1), ctx_lens, *rest)
+    axes = tuple(a for a in parallel.dp_axes if a in mesh.axis_names)
+    n = math.prod(mesh.shape[a] for a in axes)
+    nb_local, bs = nb // n, pools[0].shape[1]
+    split = q.shape[0] % n == 0
+
+    def body(q, pools, table, ctx_lens, *rest):
+        local = table - jax.lax.axis_index(axes) * nb_local
+        home = (local >= 0) & (local < nb_local)
+        live = ((jnp.arange(table.shape[1]) < -(-ctx_lens[:, None] // bs))
+                & (table < nb))
+        owner = jnp.ones_like(home[:, 0]) if split else home[:, 0]
+        foreign = jnp.any(live & ~home, axis=1) & owner
+        out = attend(q, *pools, jnp.where(home, local, 0), ctx_lens, *rest)
+        per_row = (-1,) + (1,) * (out.ndim - 1)
+        out = jnp.where(foreign.reshape(per_row), jnp.nan, out)
+        if split:
+            return out
+        return jax.lax.psum(jnp.where(owner.reshape(per_row), out, 0), axes)
+
+    row = P(axes) if split else P()
+    specs = (row, tuple(P(axes) for _ in pools), row, row) + (row,) * len(rest)
+    return jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=row,
+                         check_vma=False)(q, tuple(pools), table, ctx_lens,
+                                          *rest)
+
+
 def paged_attention_apply(cfg, p, x, positions, *, cache, block_tables,
-                          write_block, lengths):
+                          write_block, lengths, parallel=None):
     """Decode-step attention over the paged KV pool (serving/kv_blocks.py).
 
     x [B,1,D]; ``cache`` = this layer's pool leaves {'k','v':
@@ -217,7 +270,9 @@ def paged_attention_apply(cfg, p, x, positions, *, cache, block_tables,
     == NB mark inactive slots and are dropped); lengths [B] = tokens already
     cached (the new token lands at offset ``lengths % bs``).  On a quantized
     pool the new token row is quantized at write time and attention runs
-    through the fused-dequant kernel.  Returns (y [B,1,D], cache').
+    through the fused-dequant kernel.  ``parallel``: the instance's
+    ParallelCtx, whose data-parallel axes shard the pool (see
+    :func:`paged_attend`).  Returns (y [B,1,D], cache').
     """
     from repro.kernels import ops
 
@@ -251,25 +306,21 @@ def paged_attention_apply(cfg, p, x, positions, *, cache, block_tables,
                 k[:, 0].astype(cache["k"].dtype), mode="drop"),
             "v": cache["v"].at[write_block, off].set(
                 v[:, 0].astype(cache["v"].dtype), mode="drop")}
-    # table padding holds the NB sentinel (never a valid pool row); active
-    # sequences only dereference owned entries (< lengths), but inactive
-    # slots stream their padding — clamp so the gather stays in-bounds on
-    # kernels that index the pool directly (their output is discarded)
-    NB = cache["k"].shape[0]
-    bt = jnp.minimum(block_tables, NB - 1)
     if quant:
-        o = ops.quant_block_paged_decode_attention(
-            q[:, 0], cache["k"], cache["k_scale"], cache["v"],
-            cache["v_scale"], bt, lengths + 1)
+        o = paged_attend(parallel, ops.quant_block_paged_decode_attention,
+                         q[:, 0], (cache["k"], cache["k_scale"], cache["v"],
+                                   cache["v_scale"]),
+                         block_tables, lengths + 1)
     else:
-        o = ops.block_paged_decode_attention(q[:, 0], cache["k"], cache["v"],
-                                             bt, lengths + 1)
+        o = paged_attend(parallel, ops.block_paged_decode_attention, q[:, 0],
+                         (cache["k"], cache["v"]), block_tables, lengths + 1)
     y = linear(p["o"], o.reshape(B, 1, H * hd))
     return y, cache
 
 
 def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, block_tables,
-                                chunk_block_ids, ctx_len, q_len):
+                                chunk_block_ids, ctx_len, q_len,
+                                parallel=None):
     """Chunked-prefill attention over the paged KV pool (one sequence).
 
     x [1,C,D] is one prefill chunk — the last ``q_len`` (<= C) of the
@@ -280,7 +331,8 @@ def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, block_tables,
     the prompt and CoW-shared prefix blocks — those writes drop; quantized
     pools quantize per token and scatter the scales alongside), then the
     chunk attends causally over the whole context through ``block_tables``
-    [1,MB] via the mixed prefill/decode kernel.  Returns (y, cache').
+    [1,MB] via the mixed prefill/decode kernel; ``parallel`` as in
+    :func:`paged_attention_apply`.  Returns (y, cache').
     """
     from repro.kernels import ops
 
@@ -316,16 +368,14 @@ def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, block_tables,
             "v": cache["v"].at[chunk_block_ids].set(
                 v[0].reshape(C // bs, bs, KVH, hd).astype(cache["v"].dtype),
                 mode="drop")}
-    NB = cache["k"].shape[0]
-    bt = jnp.minimum(block_tables, NB - 1)
     ctx1, qlen1 = jnp.reshape(ctx_len, (1,)), jnp.reshape(q_len, (1,))
     if quant:
-        o = ops.quant_mixed_block_paged_attention(
-            q, cache["k"], cache["k_scale"], cache["v"], cache["v_scale"],
-            bt, ctx1, qlen1)
+        o = paged_attend(parallel, ops.quant_mixed_block_paged_attention, q,
+                         (cache["k"], cache["k_scale"], cache["v"],
+                          cache["v_scale"]), block_tables, ctx1, qlen1)
     else:
-        o = ops.mixed_block_paged_attention(q, cache["k"], cache["v"], bt,
-                                            ctx1, qlen1)
+        o = paged_attend(parallel, ops.mixed_block_paged_attention, q,
+                         (cache["k"], cache["v"]), block_tables, ctx1, qlen1)
     y = linear(p["o"], o.reshape(B, C, H * hd))
     return y, cache
 

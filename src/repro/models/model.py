@@ -458,7 +458,7 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, cache,
         a, lc = paged_attention_apply(
             cfg, bp["attn"], h, positions, cache=lc,
             block_tables=block_tables, write_block=write_block,
-            lengths=lengths)
+            lengths=lengths, parallel=parallel)
         x = x + a
         h = apply_norm(bp["ln2"], x, cfg.norm_type)
         out = _ffn_part(cfg, bp, h, parallel=parallel,
@@ -540,7 +540,7 @@ def paged_chunk_prefill_step(cfg: ModelConfig, params: Params, tokens, cache,
         a, lc = paged_chunk_attention_apply(
             cfg, bp["attn"], h, positions, cache=lc,
             block_tables=block_tables, chunk_block_ids=chunk_block_ids,
-            ctx_len=length, q_len=q_len)
+            ctx_len=length, q_len=q_len, parallel=parallel)
         x = x + a
         h = apply_norm(bp["ln2"], x, cfg.norm_type)
         y, _ = _ffn_part(cfg, bp, h, parallel=parallel,

@@ -36,16 +36,6 @@ import jax.numpy as jnp
 
 from repro.models.layers import dot, linear, linear_init
 
-# jax >= 0.5 exposes shard_map at top level (replication check kw is
-# ``check_vma``); 0.4.x only has the experimental module (``check_rep``).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_NOCHECK = {"check_vma": False}
-else:                                     # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
-
-
 # -------------------------------------------------------------------- router
 
 def router_init(rng, d_model, num_experts, dtype):
@@ -203,8 +193,8 @@ def _moe_ep_shard(cfg, ep_axes, tp_axis, dp_axes, router_w, wi, wg, wo, x,
     x        [T_local, D]        (token-sharded over ep_axes)
     wi/wg    [E_local, D, F_tp]  wo [E_local, F_tp, D]
 
-    ``n_ep`` is threaded in statically from the mesh (jax 0.4.x has no
-    ``jax.lax.axis_size``, and buffer shapes need it concrete anyway).
+    ``n_ep`` is threaded in statically from the mesh (buffer shapes need it
+    concrete).
     """
     E, k = cfg.num_experts, cfg.top_k
     E_local = E // n_ep
@@ -426,25 +416,25 @@ def moe_ep(cfg, p, x, parallel, capacity=None, pool=None,
             body = partial(_moe_ep_shard_pooled_quant, cfg, ep_axes, tp_axis,
                            ep_axes, capacity=C, n_ep=n_ep)
             scale_spec = P(ep_axes)
-            y, aux = _shard_map(
+            y, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(None, None), P(ep_axes, None), P(None), P(None),
                           pool_spec, pool_spec, pool_spec,
                           scale_spec, scale_spec, scale_spec, x_spec),
                 out_specs=(x_spec, P()),
-                **_SM_NOCHECK,
+                check_vma=False,
             )(p["router"]["w"], p["tables"], p["edest"], p["eslot"],
               pool["wi"], pool["wg"], pool["wo"],
               pool["wi_scale"], pool["wg_scale"], pool["wo_scale"], xf)
         else:
             body = partial(_moe_ep_shard_pooled, cfg, ep_axes, tp_axis,
                            ep_axes, capacity=C, n_ep=n_ep)
-            y, aux = _shard_map(
+            y, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(None, None), P(ep_axes, None), P(None), P(None),
                           pool_spec, pool_spec, pool_spec, x_spec),
                 out_specs=(x_spec, P()),
-                **_SM_NOCHECK,
+                check_vma=False,
             )(p["router"]["w"], p["tables"], p["edest"], p["eslot"],
               pool["wi"], pool["wg"], pool["wo"], xf)
     else:
@@ -452,11 +442,11 @@ def moe_ep(cfg, p, x, parallel, capacity=None, pool=None,
                        capacity=C, n_ep=n_ep)
         w_spec_if = P(ep_axes, None, tp_axis)
         w_spec_of = P(ep_axes, tp_axis, None)
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None), w_spec_if, w_spec_if, w_spec_of, x_spec),
             out_specs=(x_spec, P()),
-            **_SM_NOCHECK,
+            check_vma=False,
         )(p["router"]["w"], p["wi"], p["wg"], p["wo"], xf)
     if T_pad != T:
         y = y[:T]

@@ -1008,103 +1008,91 @@ def compile_step_functions(mcfg: ModelConfig, cfg: ElasticConfig, mesh,
                            kv_block_size: int = 0,
                            prefill_chunk: int = 0,
                            collect_routing: bool = False
-                           ) -> Tuple[Dict[str, Any], float]:
+                           ) -> Tuple[Dict[str, Any], Dict[str, float]]:
     """AOT-compile decode + prefill executables for an instance.
 
     ``params_sds``/``cache_sds``: pytrees of sharded ShapeDtypeStructs (no
     weights needed — pre-initialization works without the HMM, exactly the
     paper's CPU-standby instances, §4.5).  ``kv_mode='paged'`` compiles the
     block-table variants (cache_sds is then the pool layout).
-    ``prefill_chunk > 0`` additionally compiles the continuous-batching
-    chunk-prefill executable (one shape — the chunk bucket).
+    ``prefill_chunk > 0`` compiles the continuous-batching chunk-prefill
+    executable (one shape — the chunk bucket) in place of the monolithic
+    prefill buckets.
     ``collect_routing`` additionally compiles the "decode_routed" twin that
     also returns per-(layer, expert) routing counts (obs telemetry); the
     default decode path is byte-identical either way.
-    Returns (executables, seconds).
+    Returns (executables, lower+compile seconds per executable).
     """
-    t0 = time.perf_counter()
     parallel = engine_parallel_ctx(mesh)
     B = cfg.dp * batch_per_replica
     repl = NamedSharding(mesh, P())
     paged = kv_mode == "paged"
 
     out: Dict[str, Any] = {}
+    seconds: Dict[str, float] = {}
+
+    def aot(name, fn, out_shardings, *args):
+        t0 = time.perf_counter()
+        out[name] = jax.jit(fn, donate_argnums=(1,),
+                            out_shardings=out_shardings
+                            ).lower(*args).compile()
+        seconds[name] = time.perf_counter() - t0
+
+    def sd(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
     cache_out = jax.tree.map(lambda s: s.sharding, cache_sds)
-    tok_sd = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=repl)
-    rng_sd = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
-    act_sd = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=repl)
+    step_out, routed_out = (repl, cache_out), (repl, cache_out, repl)
+    tok_sd, rng_sd, act_sd = sd((B,)), sd((2,), jnp.uint32), \
+        sd((B,), jnp.bool_)
+    if collect_routing:
+        assert M.routing_stats_supported(mcfg), \
+            f"{mcfg.name}: routing telemetry unsupported"
     if paged:
         assert kv_block_size > 0 and max_len % kv_block_size == 0
         MB = max_len // kv_block_size
-        dec = jax.jit(partial(_paged_decode_fn, mcfg, parallel, temperature),
-                      donate_argnums=(1,), out_shardings=(repl, cache_out))
-        bt_sd = jax.ShapeDtypeStruct((B, MB), jnp.int32, sharding=repl)
-        out["decode"] = dec.lower(params_sds, cache_sds, tok_sd, tok_sd,
-                                  act_sd, bt_sd, rng_sd).compile()
+        step = (params_sds, cache_sds, tok_sd, tok_sd, act_sd, sd((B, MB)),
+                rng_sd)
+        aot("decode", partial(_paged_decode_fn, mcfg, parallel, temperature),
+            step_out, *step)
         if collect_routing:
-            assert M.routing_stats_supported(mcfg), \
-                f"{mcfg.name}: routing telemetry unsupported"
-            decr = jax.jit(
-                partial(_paged_decode_routed_fn, mcfg, parallel, temperature),
-                donate_argnums=(1,),
-                out_shardings=(repl, cache_out, repl))
-            out["decode_routed"] = decr.lower(
-                params_sds, cache_sds, tok_sd, tok_sd, act_sd, bt_sd,
-                rng_sd).compile()
+            aot("decode_routed", partial(_paged_decode_routed_fn, mcfg,
+                                         parallel, temperature),
+                routed_out, *step)
     else:
-        dec = jax.jit(partial(_decode_fn, mcfg, parallel, temperature),
-                      donate_argnums=(1,), out_shardings=(repl, cache_out))
-        out["decode"] = dec.lower(params_sds, cache_sds, tok_sd, tok_sd,
-                                  act_sd, rng_sd).compile()
+        step = (params_sds, cache_sds, tok_sd, tok_sd, act_sd, rng_sd)
+        aot("decode", partial(_decode_fn, mcfg, parallel, temperature),
+            step_out, *step)
         if collect_routing:
-            assert M.routing_stats_supported(mcfg), \
-                f"{mcfg.name}: routing telemetry unsupported"
-            decr = jax.jit(
-                partial(_decode_routed_fn, mcfg, parallel, temperature),
-                donate_argnums=(1,),
-                out_shardings=(repl, cache_out, repl))
-            out["decode_routed"] = decr.lower(
-                params_sds, cache_sds, tok_sd, tok_sd, act_sd,
-                rng_sd).compile()
-    for S_pad in prefill_buckets:
-        toks = jax.ShapeDtypeStruct((1, S_pad), jnp.int32, sharding=repl)
-        len_sd = jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)
+            aot("decode_routed", partial(_decode_routed_fn, mcfg, parallel,
+                                         temperature),
+                routed_out, *step)
+    # chunked mode admits every prompt through the chunk executable; the
+    # monolithic buckets would be compiled and never run
+    for S_pad in () if prefill_chunk else prefill_buckets:
         if paged:
-            pf = jax.jit(partial(_paged_prefill_fn, mcfg, parallel),
-                         donate_argnums=(1,),
-                         out_shardings=(repl, cache_out))
-            ids_sd = jax.ShapeDtypeStruct((S_pad // kv_block_size,),
-                                          jnp.int32, sharding=repl)
-            out[f"prefill_{S_pad}"] = pf.lower(
-                params_sds, cache_sds, toks, len_sd, ids_sd).compile()
+            aot(f"prefill_{S_pad}", partial(_paged_prefill_fn, mcfg,
+                                            parallel),
+                step_out, params_sds, cache_sds, sd((1, S_pad)), sd(()),
+                sd((S_pad // kv_block_size,)))
         else:
-            pf = jax.jit(partial(_prefill_fn, mcfg, parallel, max_len),
-                         donate_argnums=(1,),
-                         out_shardings=(repl, cache_out))
-            out[f"prefill_{S_pad}"] = pf.lower(
-                params_sds, cache_sds, toks, len_sd, len_sd).compile()
+            aot(f"prefill_{S_pad}", partial(_prefill_fn, mcfg, parallel,
+                                            max_len),
+                step_out, params_sds, cache_sds, sd((1, S_pad)), sd(()),
+                sd(()))
     if prefill_chunk:
         assert M.chunk_prefill_supported(mcfg), \
             "chunked prefill unsupported for this model config"
         C = prefill_chunk
-        toks = jax.ShapeDtypeStruct((1, C), jnp.int32, sharding=repl)
-        len_sd = jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)
         if paged:
             assert C % kv_block_size == 0
-            MB = max_len // kv_block_size
-            pf = jax.jit(partial(_paged_chunk_prefill_fn, mcfg, parallel),
-                         donate_argnums=(1,),
-                         out_shardings=(repl, cache_out))
-            out[f"chunk_prefill_{C}"] = pf.lower(
-                params_sds, cache_sds, toks, len_sd, len_sd,
-                jax.ShapeDtypeStruct((1, MB), jnp.int32, sharding=repl),
-                jax.ShapeDtypeStruct((C // kv_block_size,), jnp.int32,
-                                     sharding=repl)).compile()
+            aot(f"chunk_prefill_{C}", partial(_paged_chunk_prefill_fn, mcfg,
+                                              parallel),
+                step_out, params_sds, cache_sds, sd((1, C)), sd(()), sd(()),
+                sd((1, max_len // kv_block_size)), sd((C // kv_block_size,)))
         else:
-            pf = jax.jit(partial(_chunk_prefill_fn, mcfg, parallel),
-                         donate_argnums=(1,),
-                         out_shardings=(repl, cache_out))
-            out[f"chunk_prefill_{C}"] = pf.lower(
-                params_sds, cache_sds, toks, len_sd, len_sd,
-                len_sd).compile()
-    return out, time.perf_counter() - t0
+            aot(f"chunk_prefill_{C}", partial(_chunk_prefill_fn, mcfg,
+                                              parallel),
+                step_out, params_sds, cache_sds, sd((1, C)), sd(()), sd(()),
+                sd(()))
+    return out, seconds

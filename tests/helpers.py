@@ -15,6 +15,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_with_devices(code: str, ndev: int = 8, timeout: int = 600) -> str:
     env = dict(os.environ)
+    # CPU experiments: on a machine with a TPU the child never takes the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", code], env=env,

@@ -32,6 +32,7 @@ assert rec3["status"] == "ok" and rec3["roofline"]["chips"] == 512
 print("DRYRUN-OK")
 """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=900)

@@ -384,3 +384,64 @@ def test_kv_cache_write_inplace(B, S, KVH, hd, block):
     want = ref.kv_cache_write_ref(cache, new, pos)
     got = ops.kv_cache_write(cache, new, pos, block_s=block)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_paged_attention_runs_per_kv_partition():
+    """On a mesh whose 'dp' axis shards the pool, the Mosaic kernel runs
+    once per KV partition (``layers.paged_attend``; a Mosaic kernel cannot
+    be partitioned automatically).  A decode batch split across replicas,
+    and one prefill chunk answered by the replica that owns its blocks,
+    both equal the oracle over the whole pool.  A row with a live block
+    outside its home partition comes out NaN, never a wrong block's
+    answer."""
+    from helpers import run_with_devices
+    run_with_devices("""
+import functools
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.distributed.sharding import ParallelCtx
+from repro.kernels import ops, ref
+from repro.models.layers import paged_attend
+n, nbp, bs, KVH, hd, H, bpr, MB, Sq = 4, 6, 8, 2, 64, 4, 2, 3, 8
+mesh = Mesh(np.array(jax.devices()[:n]).reshape(n, 1), ("dp", "tp"))
+ctx_ = ParallelCtx(mesh=mesh, ep_axes=("dp", "tp"), tp_axis="tp",
+                   dp_axes=("dp",), moe_tp=False)
+rng = np.random.default_rng(0)
+NB = n * nbp
+pool = lambda: jnp.asarray(rng.standard_normal((NB, bs, KVH, hd)),
+                           jnp.float32)
+kp, vp = pool(), pool()
+put = lambda x: jax.device_put(x, NamedSharding(mesh, P("dp")))
+decode = jax.jit(lambda q, k, v, *a: paged_attend(
+    ctx_, functools.partial(ops.block_paged_decode_attention, impl="kernel"),
+    q, (k, v), *a))
+chunk = jax.jit(lambda q, k, v, *a: paged_attend(
+    ctx_, functools.partial(ops.mixed_block_paged_attention, impl="kernel"),
+    q, (k, v), *a))
+lens = rng.integers(1, MB * bs + 1, n * bpr).astype(np.int32)
+bt = np.full((n * bpr, MB), NB, np.int32)          # NB = padding sentinel
+for b in range(n * bpr):                    # slot b -> partition b // bpr
+    live = -(-int(lens[b]) // bs)
+    bt[b, :live] = rng.permutation(nbp)[:live] + (b // bpr) * nbp
+q = jnp.asarray(rng.standard_normal((n * bpr, H, hd)), jnp.float32)
+got = decode(q, put(kp), put(vp), bt, lens)
+want = ref.block_paged_decode_attention_ref(q, kp, vp, bt, lens)
+np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
+qc = jnp.asarray(rng.standard_normal((1, Sq, H, hd)), jnp.float32)
+ctx, ql = np.array([20], np.int32), np.array([Sq], np.int32)
+tc = np.full((1, MB), NB, np.int32)
+tc[0] = rng.permutation(nbp)[:MB] + 2 * nbp          # owned by replica 2
+got = chunk(qc, put(kp), put(vp), tc, ctx, ql)
+want = ref.mixed_block_paged_attention_ref(qc, kp, vp, tc, ctx, ql)
+np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
+# a live block of slot 0 (replica 0) moved to replica 1's partition, and a
+# chunk whose second block lies outside the partition of its first
+bad = bt.copy()
+bad[0, 0] = nbp
+tbad = tc.copy()
+tbad[0, 1] = 0
+got = np.asarray(decode(q, put(kp), put(vp), bad, lens))
+assert np.isnan(got[0]).all() and np.isfinite(got[1:]).all()
+assert np.isnan(np.asarray(chunk(qc, put(kp), put(vp), tbad, ctx, ql))).all()
+print("PARTITION-OK")
+""", ndev=4)

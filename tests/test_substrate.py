@@ -1,4 +1,5 @@
 """Training substrate + serving metrics + cost model + HLO analyzer tests."""
+import dataclasses
 import math
 import os
 
@@ -121,10 +122,13 @@ def test_hlo_analyzer_counts_loops_and_collectives():
 def test_roofline_terms_and_bottleneck():
     from repro.analysis.roofline import Roofline
     r = Roofline(flops=1e15, hbm_bytes=1e12, coll_bytes={"all-reduce": 1e11},
-                 chips=256, model_flops=5e14)
+                 chips=256, device_kind="TPU v5 lite", model_flops=5e14)
     assert abs(r.t_compute - 1e15 / (256 * 197e12)) < 1e-12
     assert r.bottleneck in ("compute", "memory", "collective")
     assert 0 < r.useful_flops_ratio < 1
+    # a chip with no published peaks is an error, not a default
+    with pytest.raises(KeyError):
+        dataclasses.replace(r, device_kind="cpu").t_compute
 
 
 def test_optimized_sharding_rules():

@@ -50,3 +50,19 @@ assert s["finished"] == 12
 print("E2E-OK", s)
 """)
     assert "E2E-OK" in out
+
+
+def test_launcher_autoscales_on_cpu():
+    """The documented CPU check of ``repro.launch.serve``: every request
+    finishes, and the queue behind one decode slot scales the instance up
+    the ladder live."""
+    out = run_with_devices("""
+from repro.launch import serve
+srv, reqs = serve.main(["--arch", "qwen3-30b-a3b-smoke", "--tp", "2",
+                        "--batch-per-replica", "1", "--requests", "4",
+                        "--autoscale"])
+assert all(r.finish_s is not None for r in reqs)
+assert srv.events and srv.hmm.active_cfg.dp > 1, srv.events
+print("LAUNCHER-OK", srv.hmm.active_cfg.describe())
+""")
+    assert "scaling up -> DP2-TP2" in out and "LAUNCHER-OK" in out

@@ -4,7 +4,8 @@
 Sweeps the static block/grid knobs of the serving kernels — decode-attention
 ``block_k``, paged-GMM / expert-FFN ``block_c``/``block_f`` — times each
 candidate, and compares achieved HBM throughput against the memory-bound
-bound from ``analysis/roofline.py`` (bytes-touched / HBM_BW).  The
+bound from ``analysis/roofline.py`` (bytes touched / the HBM peak of the
+chip it runs on, from the ``PEAKS`` table keyed by ``device_kind``).  The
 block-table and mixed prefill+decode kernels have no free knobs (their block
 size IS the pool layout's ``bs``), so they are timed and reported against
 the roofline without a sweep.  With ``--quant`` the int8 variants run at the
@@ -16,11 +17,12 @@ The winners are persisted as a JSON table (default
 caller leaves unset — a one-off offline sweep feeds the serving hot path
 with no runtime tuning machinery.
 
-On the CPU container the kernels execute in Pallas interpret mode, so
-timings rank Python emulation, not Mosaic code — useful as a dry run of the
-sweep mechanics (CI runs ``--trials 2`` and asserts the table parses), not
-as tuning data.  Run on a real TPU with ``REPRO_PALLAS_INTERPRET=0`` for
-meaningful numbers.
+On the CPU the kernels execute in Pallas interpret mode, so timings rank
+Python emulation, not Mosaic code — useful as a dry run of the sweep
+mechanics (CI runs ``--trials 2`` and asserts the table parses), not as
+tuning data, and no roofline share is reported ("not measured").  On a TPU
+the kernels compile to Mosaic and a chip missing from ``PEAKS`` is an
+error.
 
 Usage:
   python tools/autotune_kernels.py --trials 5 --out tools/autotune_best.json
@@ -42,7 +44,7 @@ import jax.numpy as jnp                                     # noqa: E402
 import numpy as np                                          # noqa: E402
 
 from repro.analysis.autotune import TUNABLE_KEYS, load_best_configs  # noqa: E402
-from repro.analysis.roofline import HBM_BW                  # noqa: E402
+from repro.analysis.roofline import peaks_for               # noqa: E402
 from repro.kernels import ops                               # noqa: E402
 from repro.kernels.quant import quantize_rows               # noqa: E402
 
@@ -207,25 +209,27 @@ RIGS = {
 }
 
 
-def sweep_kernel(name, a) -> dict:
+def sweep_kernel(name, a, hbm_bw) -> dict:
+    """``hbm_bw`` None (CPU interpreter): roofline shares not measured."""
     cands, make, nbytes, quant = RIGS[name](a)
-    t_roof = nbytes / HBM_BW
+    t_roof = nbytes / hbm_bw if hbm_bw else None
     rows = []
     for knobs in cands:
         el = _time_call(make(knobs), a.trials)
         rows.append({**knobs, "elapsed_s": el,
                      "achieved_gbps": nbytes / el / 1e9,
-                     "frac_of_roofline": t_roof / el})
+                     "frac_of_roofline": t_roof / el if t_roof else None})
     rows.sort(key=lambda r: r["elapsed_s"])
     entry = {"bytes": nbytes, "t_roofline_s": t_roof,
              "candidates": rows, "best": rows[0]}
     if a.quant and quant is not None:
         qfn, qbytes = quant()
         el = _time_call(qfn, a.trials)
+        t_qroof = qbytes / hbm_bw if hbm_bw else None
         entry["quant_int8"] = {
-            "bytes": qbytes, "t_roofline_s": qbytes / HBM_BW,
+            "bytes": qbytes, "t_roofline_s": t_qroof,
             "elapsed_s": el, "achieved_gbps": qbytes / el / 1e9,
-            "frac_of_roofline": qbytes / HBM_BW / el,
+            "frac_of_roofline": t_qroof / el if t_qroof else None,
             "bytes_vs_f32": qbytes / nbytes}
     return entry
 
@@ -258,23 +262,28 @@ def main(argv=None) -> int:
     ap.add_argument("--pool-pages", type=int, default=8)
     a = ap.parse_args(argv)
 
+    dev = jax.devices()[0]
+    hbm_bw = None if ops.on_cpu() else peaks_for(dev.device_kind).hbm_bw
     report = {"meta": {"backend": jax.default_backend(),
-                       "interpret": ops._INTERPRET,
-                       "trials": a.trials, "hbm_bw": HBM_BW,
+                       "device_kind": dev.device_kind,
+                       "interpret": ops.on_cpu(),
+                       "trials": a.trials, "hbm_bw": hbm_bw,
                        "shapes": {k: v for k, v in vars(a).items()
                                   if isinstance(v, int)}},
               "kernels": {}}
     for name in a.kernels:
         print(f"== {name}")
-        entry = sweep_kernel(name, a)
+        entry = sweep_kernel(name, a, hbm_bw)
         report["kernels"][name] = entry
         for r in entry["candidates"]:
             knobs = {k: v for k, v in r.items()
                      if k in TUNABLE_KEYS.get(name, ())}
             mark = " *" if r is entry["best"] else ""
+            frac = r["frac_of_roofline"]
+            roof = (f"{frac * 100:6.2f}% of roofline" if frac is not None
+                    else "roofline not measured")
             print(f"   {json.dumps(knobs):24s} {r['elapsed_s'] * 1e3:9.3f} ms"
-                  f"  {r['achieved_gbps']:8.3f} GB/s"
-                  f"  {r['frac_of_roofline'] * 100:6.2f}% of roofline{mark}")
+                  f"  {r['achieved_gbps']:8.3f} GB/s  {roof}{mark}")
         q = entry.get("quant_int8")
         if q:
             print(f"   int8 @ best knobs        {q['elapsed_s'] * 1e3:9.3f} ms"
