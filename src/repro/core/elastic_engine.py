@@ -47,6 +47,23 @@ from repro.serving.rebalance import RebalancePolicy
 from repro.serving.workload import Request
 
 
+def _phase_annotation(task, old: Optional[ScalePhase], new: ScalePhase,
+                      prefix: str) -> None:
+    """The live half of a task's phase span: on a transition, close the
+    profiler annotation of the phase left and open ``<prefix>.<PHASE>``
+    for the phase entered (none for a terminal one).  Phase setters run
+    on the serving thread, so the annotation lands on its lane."""
+    if old is new:
+        return
+    ann = getattr(task, "_phase_ann", None)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    task._phase_ann = None
+    if not new.terminal:
+        task._phase_ann = obs.annotate(f"{prefix}.{new.name}")
+        task._phase_ann.__enter__()
+
+
 @dataclasses.dataclass
 class ScaleEvent:
     t: float
@@ -145,6 +162,7 @@ class EngineScalingTask:
                         cat="scale", tid="scale",
                         args={"target": self.target.describe(),
                               "next": new.name})
+        _phase_annotation(self, old, new, "scale")
         self._phase_t0 = now
 
     @property
@@ -377,6 +395,7 @@ class UnparkTask:
                         cat="scale", tid="scale",
                         args={"target": self.target.describe(),
                               "next": new.name})
+        _phase_annotation(self, old, new, "unpark")
         self._phase_t0 = now
 
     @property
@@ -510,6 +529,7 @@ class RebalanceTask:
                         cat="rebalance", tid="rebalance",
                         args={"actions": len(self.actions),
                               "next": new.name})
+        _phase_annotation(self, old, new, "rebalance")
         self._phase_t0 = now
 
     @property
@@ -814,49 +834,28 @@ class ElasticServer:
         Paged KV: admission is additionally gated by free blocks in the
         target slot's partition (FIFO: the head request tries every free
         slot before admission stalls), and sequences preempted under pool
-        pressure re-enter at the *front* of the queue."""
+        pressure re-enter at the *front* of the queue.
+
+        Spans: ``srv.admit`` (admission), ``srv.step`` (the engine's
+        prefill chunks and decode step, serving/engine.py) and, while a
+        rebalance task or policy exists, ``srv.rebalance``."""
         if self.parked:
             # zero devices: nothing serves, the queue simply accrues until
             # the driver cold-starts us (a tick is legal, not an error —
             # fleet loops tick every backend uniformly)
             return []
         tr = obs.get_tracer()
-        admitting = True
-        if self._active_task is not None \
-                and not self._active_task.phase.terminal:
-            _, admitting = admission_during_scale("elastic")
-        free = self.engine.free_slots()
-        while admitting and self.queue and free:
-            req = self.queue[0]
-            # prefix-cache-aware placement: try slots whose partition
-            # already holds the longest registered prefix of this prompt
-            slot = next((s for s in
-                         self.engine.preferred_slots(req, req.prompt, free)
-                         if self.engine.can_admit(req, req.prompt, s)), None)
-            if slot is None:
-                break                   # head-of-line blocks; no skipping
-            free.remove(slot)
-            self.queue.pop(0)
-            tr.instant("req.admit", cat="req",
-                       args={"rid": req.rid, "slot": slot})
-            first = self.engine.start_request(req, req.prompt, slot)
-            if first is None:
-                continue    # chunked: first token arrives from decode_tick
-            if req.first_token_s is None:
-                req.first_token_s = now
-                req.token_times = [now]
-                tr.instant("req.first_token", cat="req",
-                           args={"rid": req.rid})
-            elif req.token_times is not None:   # preemption resume
-                req.token_times.append(now)
         finished = []
-        for rid in self.engine.drain_finished_at_admission():
-            req = self.requests[rid]
-            req.finish_s = now
-            finished.append(rid)
-            tr.instant("req.finish", cat="req", args={"rid": rid})
-            if self.estimator:
-                self.estimator.record(req)
+        with tr.span("srv.admit", cat="serve") as span:
+            admitted = self._admit(now)
+            for rid in self.engine.drain_finished_at_admission():
+                req = self.requests[rid]
+                req.finish_s = now
+                finished.append(rid)
+                tr.instant("req.finish", cat="req", args={"rid": rid})
+                if self.estimator:
+                    self.estimator.record(req)
+            span.set_metadata(admitted=admitted, queue=len(self.queue))
         for rid, tok, fin in self.engine.decode_tick():
             req = self.requests[rid]
             if req.first_token_s is None:
@@ -878,8 +877,48 @@ class ElasticServer:
         # background skew rebalance (DESIGN.md §10): advance an in-flight
         # session or let the policy open one — transfers run on the HMM's
         # TransferEngine so this never blocks the tick
-        self._drive_rebalance(now)
+        task = self._rebalance_task
+        if (task is not None and not task.done) \
+                or self.rebalance_policy is not None:
+            with tr.span("srv.rebalance", cat="serve"):
+                self._drive_rebalance(now)
         return finished
+
+    def _admit(self, now: float) -> int:
+        """Admit queued requests into free slots (FIFO, head-of-line
+        blocking); returns how many were admitted."""
+        tr = obs.get_tracer()
+        admitting = True
+        if self._active_task is not None \
+                and not self._active_task.phase.terminal:
+            _, admitting = admission_during_scale("elastic")
+        free = self.engine.free_slots()
+        n = 0
+        while admitting and self.queue and free:
+            req = self.queue[0]
+            # prefix-cache-aware placement: try slots whose partition
+            # already holds the longest registered prefix of this prompt
+            slot = next((s for s in
+                         self.engine.preferred_slots(req, req.prompt, free)
+                         if self.engine.can_admit(req, req.prompt, s)), None)
+            if slot is None:
+                break                   # head-of-line blocks; no skipping
+            free.remove(slot)
+            self.queue.pop(0)
+            n += 1
+            tr.instant("req.admit", cat="req",
+                       args={"rid": req.rid, "slot": slot})
+            first = self.engine.start_request(req, req.prompt, slot)
+            if first is None:
+                continue    # chunked: first token arrives from decode_tick
+            if req.first_token_s is None:
+                req.first_token_s = now
+                req.token_times = [now]
+                tr.instant("req.first_token", cat="req",
+                           args={"rid": req.rid})
+            elif req.token_times is not None:   # preemption resume
+                req.token_times.append(now)
+        return n
 
     # ------------------------------------------------------------ decisions
     def autoscale_decision(self, now: float) -> Optional[str]:

@@ -134,7 +134,10 @@ class TransferEngine:
         op.state = "running"
         t0 = time.perf_counter()
         try:
-            op.result = op.fn()
+            # the op's live annotation, on this worker thread's lane of a
+            # profiler trace; the ring-buffer span is recorded below
+            with obs.annotate(op.label, {"index": op.index}):
+                op.result = op.fn()
             op.state = "done"
         except BaseException as e:  # noqa: BLE001 — surfaced via failed_ops
             op.error = e

@@ -1,5 +1,9 @@
 """Unified tracing & telemetry for the serving stack (DESIGN.md §9).
 
+Every live span is also a ``jax.profiler.TraceAnnotation``, so a profiler
+trace holds it on the device ops' clock; spans recorded after the fact
+(``complete``, the simulator's sim-clock spans) stay in the ring buffer.
+
 Span schema (shared by the real engine and the simulator — a driver
 closed-loop run over either backend exports the same trace shape):
 
@@ -9,8 +13,8 @@ closed-loop run over either backend exports the same trace shape):
 | ``hmm``    | ``hmm.begin_scale/stage_increment/commit/abort/boot`` spans|
 | ``transfer``| one span per TransferOp, named by its label, emitted on  |
 |            | the worker thread that ran it (kvmig ops included)        |
-| ``serve``  | ``decode.tick`` / ``prefill.chunks`` spans, ``chunk.plan``|
-|            | / ``admit`` / ``preempt`` / ``kv.cow_copy`` instants      |
+| ``serve``  | ``srv.*`` spans (one tree per tick, DESIGN.md §9),        |
+|            | ``chunk.plan`` / ``preempt`` / ``kv.cow_copy`` instants   |
 | ``req``    | ``req.admit`` / ``req.first_token`` / ``req.finish``      |
 | ``routing``| ``routing.top_expert_share`` counter samples              |
 
@@ -23,12 +27,11 @@ Usage::
 """
 from repro.obs.export import (chrome_trace, load_trace, validate_trace,
                               write_chrome_trace)
-from repro.obs.tracer import (NULL_TRACER, MetricsRegistry, NullTracer,
-                              TraceEvent, Tracer, get_tracer, install,
-                              traced)
+from repro.obs.tracer import (NULL_TRACER, NullTracer, TraceEvent, Tracer,
+                              annotate, get_tracer, install, traced)
 
 __all__ = [
-    "Tracer", "NullTracer", "NULL_TRACER", "TraceEvent", "MetricsRegistry",
-    "install", "get_tracer", "traced",
+    "Tracer", "NullTracer", "NULL_TRACER", "TraceEvent",
+    "install", "get_tracer", "traced", "annotate",
     "chrome_trace", "write_chrome_trace", "load_trace", "validate_trace",
 ]

@@ -1,17 +1,24 @@
-"""Lock-cheap, thread-safe span tracer (DESIGN.md §9).
+"""Lock-cheap, thread-safe span tracer with a profiler sink (DESIGN.md §9).
 
-One process-global :class:`Tracer` (installed via :func:`install`) collects
-timeline events from every layer of the serving stack — scale-phase spans,
-per-``TransferOp`` worker-thread spans, decode-tick spans, request
-lifecycle instants, routing-skew counters — into a bounded ring buffer.
+Every live span (:meth:`Tracer.span`, :meth:`NullTracer.span`, the
+:func:`traced` decorator) opens a ``jax.profiler.TraceAnnotation`` of the
+same name around its body, installed tracer or not, so a JAX profiler
+trace holds the program's spans on one clock with the device ops.  Its
+args become the annotation's stats; ``set_metadata`` adds stats known only
+at the end of the body.  With no profiler running an annotation is a
+~1 µs no-op.
+
+A process-global :class:`Tracer` (installed via :func:`install`) also
+collects timeline events from every layer of the serving stack —
+scale-phase spans, per-``TransferOp`` worker-thread spans, serve-loop
+spans, request lifecycle instants, routing-skew counters — into a bounded
+ring buffer.
 
 Design constraints, in order:
 
-* **true no-op when disabled** — the default global is a
-  :data:`NULL_TRACER` singleton whose methods return immediately; hot
-  paths pay one module-global read plus an attribute call.  The
-  :func:`traced` decorator additionally short-circuits on an identity
-  check so wrapped methods skip even the context-manager protocol.
+* **cheap when off** — the default global is a :data:`NULL_TRACER`
+  singleton: its ``span`` is the bare annotation and its other methods
+  return immediately, so a hot path pays one inactive annotation per span.
 * **thread-safe without a hot-path lock** — events land in a
   ``collections.deque(maxlen=...)``; ``deque.append`` is atomic under the
   GIL, so ``TransferEngine`` worker threads and the serve loop record
@@ -21,7 +28,9 @@ Design constraints, in order:
   the simulator installs a tracer whose clock reads modelled time, and
   every recording method also accepts explicit timestamps so
   already-measured intervals (``TransferOp.t_done``) and sim-time spans
-  (``SimScaleEvent.t_command``..``t_ready``) export losslessly.
+  (``SimScaleEvent.t_command``..``t_ready``) export losslessly.  Spans
+  recorded after the fact (:meth:`Tracer.complete`) go to the ring buffer
+  only: a sim-clock span never reaches the profiler.
 
 Timestamps are stored in **seconds** (clock domain of the installed
 clock); the Chrome-trace exporter (obs/export.py) converts to µs.
@@ -34,7 +43,16 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Union
 
+from jax.profiler import TraceAnnotation
+
 Lane = Union[int, str]
+
+
+def annotate(name: str, args: Optional[dict] = None) -> TraceAnnotation:
+    """The profiler half of a span alone: a ``TraceAnnotation`` named
+    ``name`` whose stats are ``args``.  For work whose ring-buffer record
+    is written after the fact (``complete``), or that spans calls."""
+    return TraceAnnotation(name, **args) if args else TraceAnnotation(name)
 
 
 class TraceEvent:
@@ -64,9 +82,10 @@ class TraceEvent:
 
 
 class _Span:
-    """Re-entrant-free context manager emitted by :meth:`Tracer.span`."""
+    """Re-entrant-free context manager emitted by :meth:`Tracer.span`: a
+    profiler annotation plus a ring-buffer record."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_tid", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_tid", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  args: Optional[dict], tid: Optional[Lane]):
@@ -77,36 +96,22 @@ class _Span:
         self._tid = tid
 
     def __enter__(self) -> "_Span":
+        self._ann = annotate(self._name, self._args)
+        self._ann.__enter__()
         self._t0 = self._tr._clock()
         return self
 
+    def set_metadata(self, **args: Any) -> None:
+        """Add args known only once the body has run (counts)."""
+        self._args = {**(self._args or {}), **args}
+        self._ann.set_metadata(**args)
+
     def __exit__(self, *exc) -> bool:
-        self._tr.complete(self._name, self._t0, self._tr._clock(),
+        t1 = self._tr._clock()
+        self._ann.__exit__(*exc)
+        self._tr.complete(self._name, self._t0, t1,
                           cat=self._cat, args=self._args, tid=self._tid)
         return False
-
-
-class MetricsRegistry:
-    """Thread-safe counters and gauges, independent of the event buffer
-    (aggregates survive ring-buffer eviction)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._counters: Dict[str, float] = {}
-        self._gauges: Dict[str, float] = {}
-
-    def inc(self, name: str, value: float = 1) -> None:
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0) + value
-
-    def gauge(self, name: str, value: float) -> None:
-        with self._lock:
-            self._gauges[name] = value
-
-    def snapshot(self) -> Dict[str, Dict[str, float]]:
-        with self._lock:
-            return {"counters": dict(self._counters),
-                    "gauges": dict(self._gauges)}
 
 
 class Tracer:
@@ -122,7 +127,6 @@ class Tracer:
         self._events: deque = deque(maxlen=capacity)
         self._thread_names: Dict[int, str] = {}
         self._name_lock = threading.Lock()
-        self.metrics = MetricsRegistry()
 
     # ------------------------------------------------------------- clock
     def now(self) -> float:
@@ -150,8 +154,9 @@ class Tracer:
     def span(self, name: str, *, cat: str = "",
              args: Optional[dict] = None,
              tid: Optional[Lane] = None) -> _Span:
-        """``with tracer.span("decode.tick", cat="serve"): ...`` — times
-        the body with the tracer's clock."""
+        """``with tracer.span("srv.step", cat="serve"): ...`` — times
+        the body with the tracer's clock and annotates it for the
+        profiler."""
         return _Span(self, name, cat, args, tid)
 
     def instant(self, name: str, *, cat: str = "",
@@ -183,25 +188,12 @@ class Tracer:
         self._events.clear()
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """The disabled fast path: every method returns immediately.  ``now``
-    still reads the wall clock so call sites can use it unconditionally."""
+    """The disabled fast path: ``span`` is the bare profiler annotation,
+    every other method returns immediately.  ``now`` still reads the wall
+    clock so call sites can use it unconditionally."""
 
     enabled = False
-    metrics = None  # sentinel: no aggregation when disabled
 
     def now(self) -> float:
         return time.perf_counter()
@@ -209,8 +201,10 @@ class NullTracer:
     def complete(self, *a: Any, **k: Any) -> None:
         pass
 
-    def span(self, *a: Any, **k: Any) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, *, cat: str = "",
+             args: Optional[dict] = None,
+             tid: Optional[Lane] = None) -> TraceAnnotation:
+        return annotate(name, args)
 
     def instant(self, *a: Any, **k: Any) -> None:
         pass
@@ -244,14 +238,15 @@ def get_tracer() -> Union[Tracer, NullTracer]:
 
 
 def traced(name: str, cat: str = "") -> Callable:
-    """Decorator form of :meth:`Tracer.span` with a disabled-path
-    short-circuit: one global read + identity check per call."""
+    """Decorator form of :meth:`Tracer.span`; with the null tracer it is
+    one global read, an identity check and the bare annotation."""
     def deco(fn: Callable) -> Callable:
         @functools.wraps(fn)
         def wrapper(*args: Any, **kwargs: Any):
             tr = _active
             if tr is NULL_TRACER:
-                return fn(*args, **kwargs)
+                with TraceAnnotation(name):
+                    return fn(*args, **kwargs)
             with tr.span(name, cat=cat):
                 return fn(*args, **kwargs)
         return wrapper

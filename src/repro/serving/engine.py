@@ -58,6 +58,21 @@ def engine_parallel_ctx(mesh) -> ParallelCtx:
                        dp_axes=("dp",), moe_tp=False)
 
 
+# the step executables' names: module ``jit_<name>`` in HLO dumps and in
+# the profiler's trace (monolithic prefill buckets are ``prefill_<S_pad>``)
+DECODE_STEP = "decode_step"
+DECODE_STEP_ROUTED = "decode_step_routed"
+CHUNK_PREFILL = "chunk_prefill"
+
+
+def _named(name: str, fn, *args):
+    """``partial(fn, *args)`` that jits as ``jit_<name>`` (a bare partial
+    jits as ``jit__unknown``); the computation is the same."""
+    p = partial(fn, *args)
+    p.__name__ = name
+    return p
+
+
 def _sample(logits, tokens, active, rng, temperature):
     if temperature and temperature > 0:
         nxt = jax.random.categorical(
@@ -545,7 +560,7 @@ class InferenceEngine:
         parallel = engine_parallel_ctx(self.mesh)
         repl = NamedSharding(self.mesh, P())
         cache_out = jax.tree.map(lambda x: x.sharding, self.cache)
-        pf = jax.jit(partial(_paged_prefill_fn, self.mcfg, parallel),
+        pf = jax.jit(_named(key, _paged_prefill_fn, self.mcfg, parallel),
                      donate_argnums=(1,),
                      out_shardings=(repl, cache_out))
         bs = self.kv.block_size
@@ -578,7 +593,8 @@ class InferenceEngine:
 
             if self.paged:
                 pf = jax.jit(
-                    partial(_paged_chunk_prefill_fn, self.mcfg, parallel),
+                    _named(CHUNK_PREFILL, _paged_chunk_prefill_fn, self.mcfg,
+                           parallel),
                     donate_argnums=(1,), out_shardings=(repl, cache_out))
                 bs = self.kv.block_size
                 self.compiled[key] = pf.lower(
@@ -586,7 +602,8 @@ class InferenceEngine:
                     sd(()), sd(()), sd((1, self.max_len // bs)),
                     sd((C // bs,))).compile()
             else:
-                pf = jax.jit(partial(_chunk_prefill_fn, self.mcfg, parallel),
+                pf = jax.jit(_named(CHUNK_PREFILL, _chunk_prefill_fn,
+                                    self.mcfg, parallel),
                              donate_argnums=(1,),
                              out_shardings=(repl, cache_out))
                 self.compiled[key] = pf.lower(
@@ -782,64 +799,79 @@ class InferenceEngine:
             if self.slots[dst].reserved:
                 self.slots[dst] = SlotState()
 
-    @obs.traced("prefill.chunks", cat="serve")
+    @obs.traced("srv.prefill", cat="serve")
     def _run_prefill_chunks(self) -> List[Tuple[int, int, bool]]:
         """The tick's prefill phase (continuous batching): consume at most
         ``prefill_budget`` prompt tokens as ``prefill_chunk``-token buckets
         in admission order.  Chunk block ids are re-derived from the block
         manager at execution time (not admission time) so live migration
         re-homing is transparent.  Returns first-token events for jobs whose
-        final chunk landed this tick."""
+        final chunk landed this tick.
+
+        Each chunk is one ``srv.prefill.chunk`` span holding ``prep`` (the
+        host arrays), ``dispatch`` (uploads and the executable call),
+        ``register`` (paged: written blocks become matchable) and, on a
+        final chunk, ``read`` (the first token's device sync)."""
         for job in self._prefilling:
             job.paused = self.slots[job.slot].migrating
         plans = self.scheduler.plan(self._prefilling)
         out: List[Tuple[int, int, bool]] = []
         C = self.prefill_chunk
         jobs = {j.slot: j for j in self._prefilling}
+        tr = obs.get_tracer()
         for plan in plans:
             slot = plan.slot
             job = jobs[slot]
-            full, resumed = self._chunk_ctx[slot]
-            toks = np.zeros((1, C), np.int32)
-            toks[0, :plan.take] = full[plan.start:plan.start + plan.take]
-            upto = plan.start + plan.take
-            with self._cache_lock:
+            with tr.span("srv.prefill.chunk", cat="serve",
+                         args={"rid": job.rid, "start": plan.start,
+                               "take": plan.take}):
+                full, resumed = self._chunk_ctx[slot]
+                upto = plan.start + plan.take
+                with tr.span("srv.prefill.prep", cat="serve"):
+                    toks = np.zeros((1, C), np.int32)
+                    toks[0, :plan.take] = full[plan.start:upto]
+                    if self.paged:
+                        tbl, ids = self._chunk_tables(job.rid, plan.start)
+                with tr.span("srv.prefill.dispatch", cat="serve"):
+                    where = ((jnp.asarray(tbl), jnp.asarray(ids))
+                             if self.paged
+                             else (jnp.asarray(slot, jnp.int32),))
+                    with self._cache_lock:
+                        first, self.cache = self._chunk_prefill()(
+                            self.params, self.cache, jnp.asarray(toks),
+                            jnp.asarray(plan.start, jnp.int32),
+                            jnp.asarray(upto, jnp.int32), *where)
+                job.pos = upto
                 if self.paged:
-                    bs = self.kv.block_size
-                    NB = self.kv.num_blocks
-                    sb = self.kv.seq(job.rid)
-                    j0 = plan.start // bs
-                    # pool rows this chunk writes: the NB sentinel drops
-                    # writes to padding, CoW-shared prefix blocks, and (on
-                    # the rounded-down prefix_skip start) recomputed rows
-                    ids = np.full((C // bs,), NB, np.int32)
-                    for k in range(C // bs):
-                        j = j0 + k
-                        if j < len(sb.blocks) and j >= sb.num_shared:
-                            ids[k] = sb.blocks[j]
-                    tbl = np.full((1, self.max_len // bs), NB, np.int32)
-                    bt = self.kv.block_table(job.rid)
-                    tbl[0, :len(bt)] = bt
-                    first, self.cache = self._chunk_prefill()(
-                        self.params, self.cache, jnp.asarray(toks),
-                        jnp.asarray(plan.start, jnp.int32),
-                        jnp.asarray(upto, jnp.int32),
-                        jnp.asarray(tbl), jnp.asarray(ids))
-                else:
-                    first, self.cache = self._chunk_prefill()(
-                        self.params, self.cache, jnp.asarray(toks),
-                        jnp.asarray(plan.start, jnp.int32),
-                        jnp.asarray(upto, jnp.int32),
-                        jnp.asarray(slot, jnp.int32))
-            job.pos = upto
-            if self.paged:
-                # written blocks become matchable for later arrivals
-                self.kv.register_written(job.rid, [int(t) for t in full],
-                                         upto)
-            if plan.final:
-                out.append(self._finish_prefill(slot, job, int(first),
-                                                resumed))
+                    # written blocks become matchable for later arrivals
+                    with tr.span("srv.prefill.register", cat="serve"):
+                        self.kv.register_written(
+                            job.rid, [int(t) for t in full], upto)
+                if plan.final:
+                    with tr.span("srv.prefill.read", cat="serve"):
+                        first = int(first)
+                    out.append(self._finish_prefill(slot, job, first,
+                                                    resumed))
         return out
+
+    def _chunk_tables(self, rid: int, start: int):
+        """A paged chunk's block table ``[1, max_len // bs]`` and the pool
+        rows it writes ``[C // bs]``: the NB sentinel drops writes to
+        padding, CoW-shared prefix blocks, and (on the rounded-down
+        prefix_skip start) recomputed rows."""
+        bs = self.kv.block_size
+        NB = self.kv.num_blocks
+        sb = self.kv.seq(rid)
+        j0 = start // bs
+        ids = np.full((self.prefill_chunk // bs,), NB, np.int32)
+        for k in range(len(ids)):
+            j = j0 + k
+            if j < len(sb.blocks) and j >= sb.num_shared:
+                ids[k] = sb.blocks[j]
+        tbl = np.full((1, self.max_len // bs), NB, np.int32)
+        bt = self.kv.block_table(rid)
+        tbl[0, :len(bt)] = bt
+        return tbl, ids
 
     def _finish_prefill(self, slot: int, job: PrefillJob, first: int,
                         resumed: bool) -> Tuple[int, int, bool]:
@@ -865,7 +897,7 @@ class InferenceEngine:
                 self.kv.free(s.rid)
         return (s.rid, first, fin)
 
-    @obs.traced("decode.tick", cat="serve")
+    @obs.traced("srv.step", cat="serve")
     def decode_tick(self) -> List[Tuple[int, int, bool]]:
         """One engine tick.  With chunked prefill enabled the tick is a
         token-budget schedule: first the prefill phase (at most
@@ -876,66 +908,85 @@ class InferenceEngine:
         not mid-prefill, and not paused by an in-flight migration (a
         migrating sequence's blocks are frozen until the copies land, then
         it resumes on its survivor slot).  Returns [(rid, token, finished)]
-        for slots that produced a token; prefill completions come first."""
+        for slots that produced a token; prefill completions come first.
+
+        Spans: ``srv.step`` holds ``srv.prefill`` and ``srv.decode``, whose
+        children are ``prep`` (append reservations and the runnable mask),
+        ``dispatch`` (uploads and the executable call), ``read`` (the
+        tokens' device sync) and ``commit`` (per-slot bookkeeping)."""
         pre: List[Tuple[int, int, bool]] = []
         if self.scheduler is not None and self._prefilling:
             pre = self._run_prefill_chunks()
         runnable = [s.active and not s.migrating and not s.prefilling
                     for s in self.slots]
-        if self.paged:
-            # highest priority first, oldest first on ties: pressure evicts
-            # from the low-priority/young end before it reaches them
-            order = sorted((i for i in range(len(self.slots)) if runnable[i]),
-                           key=lambda i: (-self.slots[i].priority,
-                                          self.slots[i].rid))
-            for slot in order:
-                if self.slots[slot].active:
-                    self._ensure_append(slot)
-            runnable = [s.active and not s.migrating and not s.prefilling
-                        for s in self.slots]
         if not any(runnable):
             return pre
-        active = np.array(runnable)
-        self._step_count = getattr(self, "_step_count", 0) + 1
-        # routing telemetry: every Nth tick runs the counts-emitting twin
-        # executable (same math — only an extra histogram output)
-        routed = (self.routing_sample_every > 0
-                  and "decode_routed" in self.compiled
-                  and self._step_count % self.routing_sample_every == 0)
-        key = "decode_routed" if routed else "decode"
-        rng = jax.random.key_data(jax.random.PRNGKey(self._step_count))
-        with self._cache_lock:
+        with obs.get_tracer().span("srv.decode", cat="serve") as span:
+            return pre + self._decode_step(runnable, span)
+
+    def _decode_step(self, runnable: List[bool],
+                     span) -> List[Tuple[int, int, bool]]:
+        """The decode step inside the open ``srv.decode`` ``span``, which
+        gets the ``rows`` it decodes."""
+        tr = obs.get_tracer()
+        with tr.span("srv.decode.prep", cat="serve"):
             if self.paged:
+                # highest priority first, oldest first on ties: pressure
+                # evicts from the low-priority/young end before it reaches
+                # them
+                order = sorted(
+                    (i for i in range(len(self.slots)) if runnable[i]),
+                    key=lambda i: (-self.slots[i].priority,
+                                   self.slots[i].rid))
+                for slot in order:
+                    if self.slots[slot].active:
+                        self._ensure_append(slot)
+                runnable = [s.active and not s.migrating and not s.prefilling
+                            for s in self.slots]
+            active = np.array(runnable)
+        rows = int(active.sum())
+        span.set_metadata(rows=rows)
+        if rows == 0:
+            return []
+        with tr.span("srv.decode.dispatch", cat="serve"):
+            self._step_count = getattr(self, "_step_count", 0) + 1
+            # routing telemetry: every Nth tick runs the counts-emitting
+            # twin executable (same math — only an extra histogram output)
+            routed = (self.routing_sample_every > 0
+                      and "decode_routed" in self.compiled
+                      and self._step_count % self.routing_sample_every == 0)
+            key = "decode_routed" if routed else "decode"
+            rng = jax.random.key_data(jax.random.PRNGKey(self._step_count))
+            tables = (jnp.asarray(self.block_tables),) if self.paged else ()
+            with self._cache_lock:
                 res = self.compiled[key](
                     self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(active),
-                    jnp.asarray(self.block_tables), rng)
-            else:
-                res = self.compiled[key](
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(active), rng)
+                    jnp.asarray(self.lengths), jnp.asarray(active), *tables,
+                    rng)
+                if routed:
+                    nxt, self.cache, counts = res
+                else:
+                    nxt, self.cache = res
+        with tr.span("srv.decode.read", cat="serve"):
+            nxt = np.asarray(nxt)
             if routed:
-                nxt, self.cache, counts = res
-            else:
-                nxt, self.cache = res
-        if routed:
-            self._accumulate_routing(counts)
-        nxt = np.asarray(nxt)
-        out = []
-        for i, s in enumerate(self.slots):
-            if not active[i]:
-                continue
-            self.lengths[i] += 1
-            self.tokens[i] = nxt[i]
-            self.generated[s.rid].append(int(nxt[i]))
-            s.remaining -= 1
-            fin = s.remaining <= 0 or self.lengths[i] >= self.max_len - 1
-            if fin:
-                s.active = False
-                if self.paged:
-                    self.kv.free(s.rid)
-            out.append((s.rid, int(nxt[i]), fin))
-        return pre + out
+                self._accumulate_routing(counts)
+        with tr.span("srv.decode.commit", cat="serve"):
+            out = []
+            for i, s in enumerate(self.slots):
+                if not active[i]:
+                    continue
+                self.lengths[i] += 1
+                self.tokens[i] = nxt[i]
+                self.generated[s.rid].append(int(nxt[i]))
+                s.remaining -= 1
+                fin = s.remaining <= 0 or self.lengths[i] >= self.max_len - 1
+                if fin:
+                    s.active = False
+                    if self.paged:
+                        self.kv.free(s.rid)
+                out.append((s.rid, int(nxt[i]), fin))
+        return out
 
     # --------------------------------------------------- routing telemetry
     def _accumulate_routing(self, counts) -> None:
@@ -1053,31 +1104,34 @@ def compile_step_functions(mcfg: ModelConfig, cfg: ElasticConfig, mesh,
         MB = max_len // kv_block_size
         step = (params_sds, cache_sds, tok_sd, tok_sd, act_sd, sd((B, MB)),
                 rng_sd)
-        aot("decode", partial(_paged_decode_fn, mcfg, parallel, temperature),
+        aot("decode", _named(DECODE_STEP, _paged_decode_fn, mcfg, parallel,
+                             temperature),
             step_out, *step)
         if collect_routing:
-            aot("decode_routed", partial(_paged_decode_routed_fn, mcfg,
-                                         parallel, temperature),
+            aot("decode_routed", _named(DECODE_STEP_ROUTED,
+                                        _paged_decode_routed_fn, mcfg,
+                                        parallel, temperature),
                 routed_out, *step)
     else:
         step = (params_sds, cache_sds, tok_sd, tok_sd, act_sd, rng_sd)
-        aot("decode", partial(_decode_fn, mcfg, parallel, temperature),
+        aot("decode", _named(DECODE_STEP, _decode_fn, mcfg, parallel,
+                             temperature),
             step_out, *step)
         if collect_routing:
-            aot("decode_routed", partial(_decode_routed_fn, mcfg, parallel,
-                                         temperature),
+            aot("decode_routed", _named(DECODE_STEP_ROUTED, _decode_routed_fn,
+                                        mcfg, parallel, temperature),
                 routed_out, *step)
     # chunked mode admits every prompt through the chunk executable; the
     # monolithic buckets would be compiled and never run
     for S_pad in () if prefill_chunk else prefill_buckets:
         if paged:
-            aot(f"prefill_{S_pad}", partial(_paged_prefill_fn, mcfg,
-                                            parallel),
+            aot(f"prefill_{S_pad}", _named(f"prefill_{S_pad}",
+                                           _paged_prefill_fn, mcfg, parallel),
                 step_out, params_sds, cache_sds, sd((1, S_pad)), sd(()),
                 sd((S_pad // kv_block_size,)))
         else:
-            aot(f"prefill_{S_pad}", partial(_prefill_fn, mcfg, parallel,
-                                            max_len),
+            aot(f"prefill_{S_pad}", _named(f"prefill_{S_pad}", _prefill_fn,
+                                           mcfg, parallel, max_len),
                 step_out, params_sds, cache_sds, sd((1, S_pad)), sd(()),
                 sd(()))
     if prefill_chunk:
@@ -1086,13 +1140,14 @@ def compile_step_functions(mcfg: ModelConfig, cfg: ElasticConfig, mesh,
         C = prefill_chunk
         if paged:
             assert C % kv_block_size == 0
-            aot(f"chunk_prefill_{C}", partial(_paged_chunk_prefill_fn, mcfg,
-                                              parallel),
+            aot(f"chunk_prefill_{C}", _named(CHUNK_PREFILL,
+                                             _paged_chunk_prefill_fn, mcfg,
+                                             parallel),
                 step_out, params_sds, cache_sds, sd((1, C)), sd(()), sd(()),
                 sd((1, max_len // kv_block_size)), sd((C // kv_block_size,)))
         else:
-            aot(f"chunk_prefill_{C}", partial(_chunk_prefill_fn, mcfg,
-                                              parallel),
+            aot(f"chunk_prefill_{C}", _named(CHUNK_PREFILL, _chunk_prefill_fn,
+                                             mcfg, parallel),
                 step_out, params_sds, cache_sds, sd((1, C)), sd(()), sd(()),
                 sd(()))
     return out, seconds

@@ -769,7 +769,7 @@ class ServingSimulator:
             # at the roofline-modelled duration, so an overlap trace reads
             # the same on both backends (DESIGN.md §9)
             tr.complete(
-                "decode.tick", now,
+                "srv.step", now,
                 now + self.perf.decode_step_s(len(self.running), ndev),
                 cat="serve", tid="sim",
                 args={"batch": len(self.running), "ndev": ndev})

@@ -1,7 +1,7 @@
 """Acceptance tests for the tracing layer over the real concurrency surface
 (DESIGN.md §9): a closed-loop ``ClusterDriver`` run over an overlapped
 ``ElasticServer`` exports a Chrome-trace JSON in which a per-``TransferOp``
-span demonstrably overlaps a ``decode.tick`` span — the visual proof of
+span demonstrably overlaps a ``srv.step`` span — the visual proof of
 STAGING ∥ serving — and ``tools/trace_report.py`` summarizes it.  The
 simulator emits the same schema in sim-time.
 """
@@ -74,7 +74,7 @@ sys.path.insert(0, {str(REPO / "tools")!r})
 import trace_report
 n_transfer, n_overlap, n_ticks = trace_report.overlap_report(doc)
 assert n_transfer >= 1 and n_ticks >= 1, (n_transfer, n_ticks)
-assert n_overlap >= 1, "no TransferOp span overlapped a decode.tick span"
+assert n_overlap >= 1, "no TransferOp span overlapped a srv.step span"
 
 # routing histograms were sampled during the run and reach summarize()
 rt = srv.routing_stats()
@@ -134,7 +134,7 @@ def test_sim_backend_emits_same_schema_in_sim_time():
         commits = [e for e in evs if e.name == "scale.commit"]
         assert len(commits) == 1 and commits[0].ph == "i"
 
-        ticks = [e for e in evs if e.name == "decode.tick"]
+        ticks = [e for e in evs if e.name == "srv.step"]
         assert ticks and all(e.tid == "sim" for e in ticks)
         # sim clock domain: every timestamp sits inside the sim horizon,
         # nowhere near time.perf_counter()'s wall-clock origin

@@ -10,8 +10,9 @@ Three sections:
 * **phase timeline** — scale-phase spans (cat ``scale``) and HMM staging
   spans in start order with text bars, the at-a-glance view of the
   STAGING ∥ COMPILING ∥ MIGRATING concurrency claim;
-* **overlap** — how many ``transfer`` spans overlapped a ``decode.tick``
-  span in wall-clock (the paper's serving-while-staging evidence).
+* **overlap** — how many ``transfer`` spans overlapped a ``srv.step``
+  span (one engine tick's prefill and decode) in wall-clock (the paper's
+  serving-while-staging evidence).
 
 Stdlib only; works on traces from the real engine (perf_counter domain)
 and the simulator (sim-time domain) alike.
@@ -84,9 +85,9 @@ def print_timeline(doc, max_rows=40, file=sys.stdout):
 
 def overlap_report(doc):
     """(n_transfer, n_overlapping, decode_ticks) — a transfer span counts
-    as overlapping when any decode.tick span intersects it in time."""
+    as overlapping when any srv.step span intersects it in time."""
     transfers = list(_spans(doc, "transfer"))
-    ticks = [r for r in _spans(doc, "serve") if r["name"] == "decode.tick"]
+    ticks = [r for r in _spans(doc, "serve") if r["name"] == "srv.step"]
     n_overlap = 0
     for tr in transfers:
         a0, a1 = tr["ts"], tr["ts"] + tr["dur"]
