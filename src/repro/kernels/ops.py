@@ -116,62 +116,66 @@ def paged_decode_attention(q, k_cache, v_cache, lengths, **kw):
 
 
 def block_paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                                 **kw):
-    """Block-table paged decode (serving hot path; see kv_blocks.py);
-    ``impl``/``REPRO_PAGED_IMPL`` as in the module docstring.  On a mesh,
-    ``models.layers.paged_attend`` runs it once per KV partition."""
+                                 layer=None, **kw):
+    """Block-table paged decode (serving hot path; see kv_blocks.py) over
+    stacked pools [L, NB, ...] read at ``layer``, or one layer's pools with
+    ``layer=None``; ``impl``/``REPRO_PAGED_IMPL`` as in the module
+    docstring.  On a mesh, ``models.layers.paged_attend`` runs it once per
+    KV partition."""
     if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import block_paged_decode_attention_ref
         return block_paged_decode_attention_ref(q, k_pool, v_pool,
-                                                block_tables, lengths)
+                                                block_tables, lengths, layer)
     from repro.kernels.paged_attention import \
         block_paged_decode_attention as _block_paged
-    return _block_paged(q, k_pool, v_pool, block_tables, lengths,
+    return _block_paged(q, k_pool, v_pool, block_tables, lengths, layer,
                         **_interpret(kw))
 
 
 def quant_block_paged_decode_attention(q, k_pool, k_scale, v_pool, v_scale,
-                                       block_tables, lengths, **kw):
+                                       block_tables, lengths, layer=None,
+                                       **kw):
     """Int8 block-table paged decode (per-token f32 scale pools riding the
-    block table)."""
+    layer and block table)."""
     if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import quant_block_paged_decode_attention_ref
         return quant_block_paged_decode_attention_ref(
-            q, k_pool, k_scale, v_pool, v_scale, block_tables, lengths)
+            q, k_pool, k_scale, v_pool, v_scale, block_tables, lengths, layer)
     from repro.kernels.paged_attention import \
         quant_block_paged_decode_attention as _qblock
     return _qblock(q, k_pool, k_scale, v_pool, v_scale, block_tables, lengths,
-                   **_interpret(kw))
+                   layer, **_interpret(kw))
 
 
 def mixed_block_paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                                q_lens, **kw):
+                                q_lens, layer=None, **kw):
     """Mixed chunked-prefill / decode attention over the block pool (the
     continuous-batching hot path; see serving/scheduler.py); arguments as
     ``block_paged_decode_attention``."""
     if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import mixed_block_paged_attention_ref
         return mixed_block_paged_attention_ref(q, k_pool, v_pool,
-                                               block_tables, ctx_lens, q_lens)
+                                               block_tables, ctx_lens, q_lens,
+                                               layer)
     from repro.kernels.paged_attention import \
         mixed_block_paged_attention as _mixed
-    return _mixed(q, k_pool, v_pool, block_tables, ctx_lens, q_lens,
+    return _mixed(q, k_pool, v_pool, block_tables, ctx_lens, q_lens, layer,
                   **_interpret(kw))
 
 
 def quant_mixed_block_paged_attention(q, k_pool, k_scale, v_pool, v_scale,
                                       block_tables, ctx_lens, q_lens,
-                                      **kw):
+                                      layer=None, **kw):
     """Int8 mixed chunked-prefill / decode attention."""
     if _use_ref(kw, "REPRO_PAGED_IMPL"):
         from repro.kernels.ref import quant_mixed_block_paged_attention_ref
         return quant_mixed_block_paged_attention_ref(
             q, k_pool, k_scale, v_pool, v_scale, block_tables, ctx_lens,
-            q_lens)
+            q_lens, layer)
     from repro.kernels.paged_attention import \
         quant_mixed_block_paged_attention as _qmixed
     return _qmixed(q, k_pool, k_scale, v_pool, v_scale, block_tables,
-                   ctx_lens, q_lens, **_interpret(kw))
+                   ctx_lens, q_lens, layer, **_interpret(kw))
 
 
 def ssd_scan(x, dt, A, Bm, Cm, **kw):

@@ -68,15 +68,24 @@ def paged_decode_attention_ref(q, k_cache, v_cache, lengths):
     return o.reshape(B, H, hd).astype(q.dtype)
 
 
-def block_paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths):
-    """Block-table paged decode: q [B,H,hd]; k/v_pool [NB,bs,KVH,hd];
-    block_tables [B,MB] (pool indices; entries past a sequence's length are
-    don't-care); lengths [B] -> [B,H,hd].
+def _at_layer(layer, *pools):
+    """One layer of stacked pools [L, NB, ...]; ``layer=None`` means the
+    pools already are one layer's [NB, ...]."""
+    return pools if layer is None else tuple(p[layer] for p in pools)
+
+
+def block_paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths,
+                                     layer=None):
+    """Block-table paged decode: q [B,H,hd]; k/v_pool [NB,bs,KVH,hd], or
+    [L,NB,bs,KVH,hd] read at ``layer``; block_tables [B,MB] (pool indices;
+    entries past a sequence's length are don't-care); lengths [B] ->
+    [B,H,hd].
 
     Gathers each sequence's K/V through its block table into a contiguous
     [B, MB*bs, KVH, hd] view, then runs the dense masked decode attention —
     the oracle the Pallas kernel (and the engine's CPU fallback) must match.
     """
+    k_pool, v_pool = _at_layer(layer, k_pool, v_pool)
     B, H, hd = q.shape
     bs, KVH = k_pool.shape[1], k_pool.shape[2]
     MB = block_tables.shape[1]
@@ -86,19 +95,23 @@ def block_paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths):
 
 
 def quant_block_paged_decode_attention_ref(q, k_pool, k_scale, v_pool,
-                                           v_scale, block_tables, lengths):
+                                           v_scale, block_tables, lengths,
+                                           layer=None):
     """Dequant-then-delegate oracle for the int8 block-table paged decode:
     k/v_pool int8 [NB,bs,KVH,hd], k/v_scale f32 [NB,bs] (one per token row,
-    ``quantize_rows`` over (KVH, hd))."""
+    ``quantize_rows`` over (KVH, hd)); stacked [L, ...] read at ``layer``."""
     from repro.kernels.quant import dequantize_rows
+    k_pool, k_scale, v_pool, v_scale = _at_layer(layer, k_pool, k_scale,
+                                                 v_pool, v_scale)
     k = dequantize_rows(k_pool, k_scale, (-2, -1))
     v = dequantize_rows(v_pool, v_scale, (-2, -1))
     return block_paged_decode_attention_ref(q, k, v, block_tables, lengths)
 
 
 def mixed_block_paged_attention_ref(q, k_pool, v_pool, block_tables,
-                                    ctx_lens, q_lens):
-    """Mixed chunked-prefill / decode attention over the block pool.
+                                    ctx_lens, q_lens, layer=None):
+    """Mixed chunked-prefill / decode attention over the block pool
+    (pools as in :func:`block_paged_decode_attention_ref`).
 
     q [B,Sq,H,hd]: row ``i`` of sequence ``b`` is the query at absolute
     position ``ctx_lens[b] - q_lens[b] + i`` — a prefill chunk is the last
@@ -109,6 +122,7 @@ def mixed_block_paged_attention_ref(q, k_pool, v_pool, block_tables,
     deterministic, but callers discard them.  Sentinel block-table entries
     (``NB``) are clamped in-bounds; position masking keeps them inert.
     """
+    k_pool, v_pool = _at_layer(layer, k_pool, v_pool)
     B, Sq, H, hd = q.shape
     NB, bs, KVH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     MB = block_tables.shape[1]
@@ -131,10 +145,12 @@ def mixed_block_paged_attention_ref(q, k_pool, v_pool, block_tables,
 
 def quant_mixed_block_paged_attention_ref(q, k_pool, k_scale, v_pool,
                                           v_scale, block_tables, ctx_lens,
-                                          q_lens):
+                                          q_lens, layer=None):
     """Dequant-then-delegate oracle for the int8 mixed prefill/decode
     attention (same scale layout as the quant block-decode oracle)."""
     from repro.kernels.quant import dequantize_rows
+    k_pool, k_scale, v_pool, v_scale = _at_layer(layer, k_pool, k_scale,
+                                                 v_pool, v_scale)
     k = dequantize_rows(k_pool, k_scale, (-2, -1))
     v = dequantize_rows(v_pool, v_scale, (-2, -1))
     return mixed_block_paged_attention_ref(q, k, v, block_tables, ctx_lens,

@@ -205,21 +205,23 @@ def attention_apply(cfg, p, x, positions, *, cache=None, write_pos=None,
     return linear(p["o"], y.reshape(B, Sq, H * hd)), new_cache
 
 
-def paged_attend(parallel, attend, q, pools, table, ctx_lens, *rest):
-    """``attend(q, *pools, table, ctx_lens, *rest)`` over the paged pool.
+def paged_attend(parallel, attend, q, pools, layer, table, ctx_lens, *rest):
+    """``attend(q, *pools, table, ctx_lens, *rest, layer=layer)`` over the
+    paged pool.
 
-    ``pools``: the KV pool leaves, block axis first; ``table`` [B,MB]
-    global block ids, ``NB`` marking padding.  Without a multi-device mesh
-    the table is clamped in-bounds and ``attend`` runs once.  A Mosaic
-    kernel cannot be partitioned automatically, so on a mesh ``attend``
-    runs once per KV partition: the pool's block axis is sharded over the
-    data-parallel axes of ``parallel``, one partition per replica, and
-    every block of a sequence lives in its replica's partition
-    (serving/kv_blocks.py; migration re-homes blocks before their slot
-    moves).  Inside the ``shard_map`` the table is rebased onto the local
-    partition.  A batch that divides over the replicas is split across
-    them, row ``b`` to replica ``b // (B / replicas)``; otherwise (one
-    prefill chunk) every replica runs the whole batch against its own
+    ``pools``: the stacked KV pool leaves ``[L, NB, ...]``, block axis 1,
+    read at pool layer ``layer`` (a scalar); ``table`` [B,MB] global block
+    ids, ``NB`` marking padding.  Without a multi-device mesh the table is
+    clamped in-bounds and ``attend`` runs once.  A Mosaic kernel cannot be
+    partitioned automatically, so on a mesh ``attend`` runs once per KV
+    partition: the pool's block axis is sharded over the data-parallel axes
+    of ``parallel``, one partition per replica, and every block of a
+    sequence lives in its replica's partition (serving/kv_blocks.py;
+    migration re-homes blocks before their slot moves).  Inside the
+    ``shard_map`` the table is rebased onto the local partition; the layer
+    index is replicated.  A batch that divides over the replicas is split
+    across them, row ``b`` to replica ``b // (B / replicas)``; otherwise
+    (one prefill chunk) every replica runs the whole batch against its own
     partition and the replica owning a row's first block keeps that row —
     the others' work is discarded.  A row with a live block outside its
     home partition comes out NaN, never a plausible wrong answer (a
@@ -228,23 +230,26 @@ def paged_attend(parallel, attend, q, pools, table, ctx_lens, *rest):
     """
     from jax.sharding import PartitionSpec as P
 
-    nb = pools[0].shape[0]
+    nb = pools[0].shape[1]
+    layer = jnp.asarray(layer, jnp.int32)
     mesh = getattr(parallel, "mesh", None)
     if mesh is None or mesh.size == 1:
-        return attend(q, *pools, jnp.minimum(table, nb - 1), ctx_lens, *rest)
+        return attend(q, *pools, jnp.minimum(table, nb - 1), ctx_lens, *rest,
+                      layer=layer)
     axes = tuple(a for a in parallel.dp_axes if a in mesh.axis_names)
     n = math.prod(mesh.shape[a] for a in axes)
-    nb_local, bs = nb // n, pools[0].shape[1]
+    nb_local, bs = nb // n, pools[0].shape[2]
     split = q.shape[0] % n == 0
 
-    def body(q, pools, table, ctx_lens, *rest):
+    def body(q, pools, layer, table, ctx_lens, *rest):
         local = table - jax.lax.axis_index(axes) * nb_local
         home = (local >= 0) & (local < nb_local)
         live = ((jnp.arange(table.shape[1]) < -(-ctx_lens[:, None] // bs))
                 & (table < nb))
         owner = jnp.ones_like(home[:, 0]) if split else home[:, 0]
         foreign = jnp.any(live & ~home, axis=1) & owner
-        out = attend(q, *pools, jnp.where(home, local, 0), ctx_lens, *rest)
+        out = attend(q, *pools, jnp.where(home, local, 0), ctx_lens, *rest,
+                     layer=layer)
         per_row = (-1,) + (1,) * (out.ndim - 1)
         out = jnp.where(foreign.reshape(per_row), jnp.nan, out)
         if split:
@@ -252,33 +257,68 @@ def paged_attend(parallel, attend, q, pools, table, ctx_lens, *rest):
         return jax.lax.psum(jnp.where(owner.reshape(per_row), out, 0), axes)
 
     row = P(axes) if split else P()
-    specs = (row, tuple(P(axes) for _ in pools), row, row) + (row,) * len(rest)
+    specs = ((row, tuple(P(None, axes) for _ in pools), P(), row, row)
+             + (row,) * len(rest))
     return jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=row,
-                         check_vma=False)(q, tuple(pools), table, ctx_lens,
-                                          *rest)
+                         check_vma=False)(q, tuple(pools), layer, table,
+                                          ctx_lens, *rest)
 
 
-def paged_attention_apply(cfg, p, x, positions, *, cache, block_tables,
-                          write_block, lengths, parallel=None):
+def _write_pool(cache, layer, idx, k, v):
+    """Scatter new K/V into pool layer ``layer`` of the stacked pool at
+    ``idx`` (a tuple of index arrays over the block axis and beyond; ``NB``
+    block ids drop).  The pool is the loop carry of the layer scan and is
+    donated by the step, so this is a small in-place scatter.  A quantized
+    pool quantizes per token row and scatters the scales alongside."""
+    at = (layer, *idx)
+    if "k_scale" not in cache:
+        return {n: cache[n].at[at].set(new.astype(cache[n].dtype),
+                                       mode="drop")
+                for n, new in (("k", k), ("v", v))}
+    from repro.kernels.quant import quantize_rows
+    cache = dict(cache)
+    for n, new in (("k", k), ("v", v)):
+        qr, s = quantize_rows(new, (-2, -1))
+        cache[n] = cache[n].at[at].set(qr, mode="drop")
+        cache[n + "_scale"] = cache[n + "_scale"].at[at].set(s, mode="drop")
+    return cache
+
+
+def _attend_pool(parallel, kernel, quant_kernel, q, cache, layer, table,
+                 ctx_lens, *rest):
+    """Attention of ``q`` over pool layer ``layer`` of the stacked pool,
+    through the int8 kernel (scales beside the entries) when the pool is
+    quantized."""
+    if "k_scale" in cache:
+        return paged_attend(parallel, quant_kernel, q,
+                            (cache["k"], cache["k_scale"], cache["v"],
+                             cache["v_scale"]), layer, table, ctx_lens, *rest)
+    return paged_attend(parallel, kernel, q, (cache["k"], cache["v"]), layer,
+                        table, ctx_lens, *rest)
+
+
+def paged_attention_apply(cfg, p, x, positions, *, cache, layer,
+                          block_tables, write_block, lengths, parallel=None):
     """Decode-step attention over the paged KV pool (serving/kv_blocks.py).
 
-    x [B,1,D]; ``cache`` = this layer's pool leaves {'k','v':
-    [NB,bs,KVH,hd]} — plus per-token f32 scale pools {'k_scale','v_scale':
-    [NB,bs]} when the pool is int8 (DESIGN.md §11); block_tables [B,MB];
-    write_block [B] = pool row receiving this step's k/v (the engine
-    guarantees it is uniquely owned — CoW happened before the step; entries
-    == NB mark inactive slots and are dropped); lengths [B] = tokens already
-    cached (the new token lands at offset ``lengths % bs``).  On a quantized
-    pool the new token row is quantized at write time and attention runs
-    through the fused-dequant kernel.  ``parallel``: the instance's
-    ParallelCtx, whose data-parallel axes shard the pool (see
-    :func:`paged_attend`).  Returns (y [B,1,D], cache').
+    x [B,1,D]; ``cache`` = the whole stacked pool {'k','v':
+    [L,NB,bs,KVH,hd]} — plus per-token f32 scale pools {'k_scale',
+    'v_scale': [L,NB,bs]} when the pool is int8 (DESIGN.md §11) — of which
+    this layer is pool layer ``layer``; block_tables [B,MB]; write_block
+    [B] = pool row receiving this step's k/v (the engine guarantees it is
+    uniquely owned — CoW happened before the step; entries == NB mark
+    inactive slots and are dropped); lengths [B] = tokens already cached
+    (the new token lands at offset ``lengths % bs``).  On a quantized pool
+    the new token row is quantized at write time and attention runs through
+    the fused-dequant kernel.  ``parallel``: the instance's ParallelCtx,
+    whose data-parallel axes shard the pool (see :func:`paged_attend`).
+    Returns (y [B,1,D], cache').
     """
     from repro.kernels import ops
 
     B, _, D = x.shape
     H, KVH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[2]
 
     q = linear(p["q"], x).reshape(B, 1, H, hd)
     k = linear(p["k"], x).reshape(B, 1, KVH, hd)
@@ -289,56 +329,37 @@ def paged_attention_apply(cfg, p, x, positions, *, cache, block_tables,
         q = apply_rope(q, cos, sin, rot_dim)
         k = apply_rope(k, cos, sin, rot_dim)
 
-    off = lengths % bs
-    quant = "k_scale" in cache
-    if quant:
-        from repro.kernels.quant import quantize_rows
-        cache = dict(cache)
-        for name, new in (("k", k), ("v", v)):
-            qr, s = quantize_rows(new[:, 0], (-2, -1))   # [B,KVH,hd] rows
-            cache[name] = cache[name].at[write_block, off].set(
-                qr, mode="drop")
-            cache[name + "_scale"] = cache[name + "_scale"].at[
-                write_block, off].set(s, mode="drop")
-    else:
-        cache = {
-            "k": cache["k"].at[write_block, off].set(
-                k[:, 0].astype(cache["k"].dtype), mode="drop"),
-            "v": cache["v"].at[write_block, off].set(
-                v[:, 0].astype(cache["v"].dtype), mode="drop")}
-    if quant:
-        o = paged_attend(parallel, ops.quant_block_paged_decode_attention,
-                         q[:, 0], (cache["k"], cache["k_scale"], cache["v"],
-                                   cache["v_scale"]),
-                         block_tables, lengths + 1)
-    else:
-        o = paged_attend(parallel, ops.block_paged_decode_attention, q[:, 0],
-                         (cache["k"], cache["v"]), block_tables, lengths + 1)
+    cache = _write_pool(cache, layer, (write_block, lengths % bs),
+                        k[:, 0], v[:, 0])
+    o = _attend_pool(parallel, ops.block_paged_decode_attention,
+                     ops.quant_block_paged_decode_attention, q[:, 0], cache,
+                     layer, block_tables, lengths + 1)
     y = linear(p["o"], o.reshape(B, 1, H * hd))
     return y, cache
 
 
-def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, block_tables,
-                                chunk_block_ids, ctx_len, q_len,
+def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, layer,
+                                block_tables, chunk_block_ids, ctx_len, q_len,
                                 parallel=None):
     """Chunked-prefill attention over the paged KV pool (one sequence).
 
     x [1,C,D] is one prefill chunk — the last ``q_len`` (<= C) of the
     sequence's first ``ctx_len`` tokens; ``positions`` [1,C] are their
-    absolute positions.  ``cache`` = this layer's pool leaves as in
+    absolute positions.  ``cache`` and ``layer`` as in
     :func:`paged_attention_apply`.  The chunk's k/v are scattered into the
-    pool rows ``chunk_block_ids`` [C/bs] first (``NB`` marks padding beyond
-    the prompt and CoW-shared prefix blocks — those writes drop; quantized
-    pools quantize per token and scatter the scales alongside), then the
-    chunk attends causally over the whole context through ``block_tables``
-    [1,MB] via the mixed prefill/decode kernel; ``parallel`` as in
-    :func:`paged_attention_apply`.  Returns (y, cache').
+    pool rows ``chunk_block_ids`` [C/bs] of pool layer ``layer`` first
+    (``NB`` marks padding beyond the prompt and CoW-shared prefix blocks —
+    those writes drop; quantized pools quantize per token and scatter the
+    scales alongside), then the chunk attends causally over the whole
+    context through ``block_tables`` [1,MB] via the mixed prefill/decode
+    kernel; ``parallel`` as in :func:`paged_attention_apply`.  Returns
+    (y, cache').
     """
     from repro.kernels import ops
 
     B, C, D = x.shape
     H, KVH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[2]
 
     q = linear(p["q"], x).reshape(B, C, H, hd)
     k = linear(p["k"], x).reshape(B, C, KVH, hd)
@@ -349,33 +370,15 @@ def paged_chunk_attention_apply(cfg, p, x, positions, *, cache, block_tables,
         q = apply_rope(q, cos, sin, rot_dim)
         k = apply_rope(k, cos, sin, rot_dim)
 
-    quant = "k_scale" in cache
-    if quant:
-        from repro.kernels.quant import quantize_rows
-        cache = dict(cache)
-        for name, new in (("k", k), ("v", v)):
-            qr, s = quantize_rows(new[0].reshape(C // bs, bs, KVH, hd),
-                                  (-2, -1))
-            cache[name] = cache[name].at[chunk_block_ids].set(
-                qr, mode="drop")
-            cache[name + "_scale"] = cache[name + "_scale"].at[
-                chunk_block_ids].set(s, mode="drop")
-    else:
-        cache = {
-            "k": cache["k"].at[chunk_block_ids].set(
-                k[0].reshape(C // bs, bs, KVH, hd).astype(cache["k"].dtype),
-                mode="drop"),
-            "v": cache["v"].at[chunk_block_ids].set(
-                v[0].reshape(C // bs, bs, KVH, hd).astype(cache["v"].dtype),
-                mode="drop")}
-    ctx1, qlen1 = jnp.reshape(ctx_len, (1,)), jnp.reshape(q_len, (1,))
-    if quant:
-        o = paged_attend(parallel, ops.quant_mixed_block_paged_attention, q,
-                         (cache["k"], cache["k_scale"], cache["v"],
-                          cache["v_scale"]), block_tables, ctx1, qlen1)
-    else:
-        o = paged_attend(parallel, ops.mixed_block_paged_attention, q,
-                         (cache["k"], cache["v"]), block_tables, ctx1, qlen1)
+    # one row per token, as the decode write: a scatter of whole blocks
+    # makes XLA pick another layout for the pool, and copy all of it to
+    # and from the kernel's layout on every layer
+    rows = (jnp.repeat(chunk_block_ids, bs), jnp.tile(jnp.arange(bs), C // bs))
+    cache = _write_pool(cache, layer, rows, k[0], v[0])
+    o = _attend_pool(parallel, ops.mixed_block_paged_attention,
+                     ops.quant_mixed_block_paged_attention, q, cache, layer,
+                     block_tables, jnp.reshape(ctx_len, (1,)),
+                     jnp.reshape(q_len, (1,)))
     y = linear(p["o"], o.reshape(B, C, H * hd))
     return y, cache
 

@@ -369,7 +369,10 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     One pool row per (layer, block); ``serving/kv_blocks.py`` owns which
     sequence maps to which rows.  The block axis is sharded over 'dp'
     (one partition of ``NB/dp`` rows per replica), so growing the instance
-    appends partitions and surviving rows are reused zero-copy.
+    appends partitions and surviving rows are reused zero-copy.  The
+    paged steps carry the whole stacked pool through the layer scan and
+    never slice it: layer ``l`` writes and reads pool layer ``l`` by index,
+    inside the attention kernel (``kernels/paged_attention.py``).
 
     ``kv_dtype="int8"`` stores entries quantized (DESIGN.md §11): the pools
     become int8 and per-token f32 scale pools ``{'k_scale','v_scale':
@@ -452,11 +455,10 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, cache,
     positions = lengths[:, None]
     moe = cfg.is_moe
 
-    def block(bp, x, lc, want_counts=False):
-        # lc: this layer's cache leaves ({'k','v'} + optional int8 scales)
+    def block(bp, x, cache, layer, want_counts=False):
         h = apply_norm(bp["ln1"], x, cfg.norm_type)
-        a, lc = paged_attention_apply(
-            cfg, bp["attn"], h, positions, cache=lc,
+        a, cache = paged_attention_apply(
+            cfg, bp["attn"], h, positions, cache=cache, layer=layer,
             block_tables=block_tables, write_block=write_block,
             lengths=lengths, parallel=parallel)
         x = x + a
@@ -467,36 +469,28 @@ def paged_decode_step(cfg: ModelConfig, params: Params, tokens, cache,
                         return_counts=want_counts)
         if want_counts:
             y, _, cnt = out
-            return x + y, lc, cnt
+            return x + y, cache, cnt
         y, _ = out
-        return x + y, lc
+        return x + y, cache
 
+    # the stacked pool is the scan's carry, not a scanned input: each layer
+    # writes and reads its own pool layer in place, by index
     nk = cfg.first_k_dense if moe else 0
-    prefix = []
     for i in range(nk):
-        x, lc = block(params["dense_prefix"][i], x,
-                      {n: v[i] for n, v in cache.items()})
-        prefix.append(lc)
+        x, cache = block(params["dense_prefix"][i], x, cache, i)
 
-    def body(x, inp):
-        bp, lc = inp
+    def body(carry, inp):
+        x, cache = carry
+        bp, layer = inp
         if collect_routing:
-            x, lc, cnt = block(bp, x, lc, want_counts=True)
-            return x, (lc, cnt)
-        x, lc = block(bp, x, lc)
-        return x, lc
+            x, cache, cnt = block(bp, x, cache, layer, want_counts=True)
+            return (x, cache), cnt
+        x, cache = block(bp, x, cache, layer)
+        return (x, cache), None
 
-    x, scanned = jax.lax.scan(body, x, (params["blocks"],
-                                        {n: v[nk:] for n, v in cache.items()}))
-    counts = None
-    if collect_routing:
-        new_cache, counts = scanned
-    else:
-        new_cache = scanned
-    if nk:
-        new_cache = {n: jnp.concatenate(
-            [jnp.stack([p[n] for p in prefix]), new_cache[n]], 0)
-            for n in new_cache}
+    (x, new_cache), counts = jax.lax.scan(
+        body, (x, cache),
+        (params["blocks"], jnp.arange(nk, cfg.num_layers, dtype=jnp.int32)))
 
     x = apply_norm(params["final_norm"], x, cfg.norm_type)
     logits = linear(params["lm_head"], x[:, 0])
@@ -535,10 +529,10 @@ def paged_chunk_prefill_step(cfg: ModelConfig, params: Params, tokens, cache,
     q_len = length - start
     moe = cfg.is_moe
 
-    def block(bp, x, lc):
+    def block(bp, x, cache, layer):
         h = apply_norm(bp["ln1"], x, cfg.norm_type)
-        a, lc = paged_chunk_attention_apply(
-            cfg, bp["attn"], h, positions, cache=lc,
+        a, cache = paged_chunk_attention_apply(
+            cfg, bp["attn"], h, positions, cache=cache, layer=layer,
             block_tables=block_tables, chunk_block_ids=chunk_block_ids,
             ctx_len=length, q_len=q_len, parallel=parallel)
         x = x + a
@@ -546,27 +540,21 @@ def paged_chunk_prefill_step(cfg: ModelConfig, params: Params, tokens, cache,
         y, _ = _ffn_part(cfg, bp, h, parallel=parallel,
                          moe=moe and "moe" in bp,
                          moe_pool=params.get("moe_pool"))
-        return x + y, lc
+        return x + y, cache
 
+    # the stacked pool is the scan's carry, as in paged_decode_step
     nk = cfg.first_k_dense if moe else 0
-    prefix = []
     for i in range(nk):
-        x, lc = block(params["dense_prefix"][i], x,
-                      {n: v[i] for n, v in cache.items()})
-        prefix.append(lc)
+        x, cache = block(params["dense_prefix"][i], x, cache, i)
 
-    def body(x, inp):
-        bp, lc = inp
-        x, lc = block(bp, x, lc)
-        return x, lc
+    def body(carry, inp):
+        x, cache = carry
+        bp, layer = inp
+        return block(bp, x, cache, layer), None
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"],
-                                          {n: v[nk:]
-                                           for n, v in cache.items()}))
-    if nk:
-        new_cache = {n: jnp.concatenate(
-            [jnp.stack([p[n] for p in prefix]), new_cache[n]], 0)
-            for n in new_cache}
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (params["blocks"], jnp.arange(nk, cfg.num_layers, dtype=jnp.int32)))
 
     x = apply_norm(params["final_norm"], x, cfg.norm_type)
     last = jax.lax.dynamic_index_in_dim(x, q_len - 1, axis=1, keepdims=False)

@@ -163,57 +163,88 @@ def test_paged_decode_attention(B, H, KVH, hd, S, dtype):
                                np.asarray(want, np.float32), **tol(dtype))
 
 
+def _pool_cases(*cases):
+    """Kernel shape cases; a trailing ``(L, layer)`` reads pool layer
+    ``layer`` of a stacked ``[L, NB, ...]`` pool, ``None`` a single-layer
+    ``[NB, ...]`` pool (the case id is then the shape alone)."""
+    return [pytest.param(*c, id="-".join(map(str, c[:-1])) + (
+        "" if c[-1] is None else "-L{}-layer{}".format(*c[-1])))
+        for c in cases]
+
+
+def _pools(NB, bs, KVH, hd, dtype, stack):
+    """(k_pool, v_pool, k_at, v_at, layer): the pools the kernel reads and
+    the single layer the oracle reads; the other layers are different
+    random rows, so a kernel reading the wrong layer cannot pass."""
+    shape = (NB, bs, KVH, hd) if stack is None else (stack[0], NB, bs, KVH,
+                                                      hd)
+    kp = jnp.asarray(RNG.standard_normal(shape), dtype)
+    vp = jnp.asarray(RNG.standard_normal(shape), dtype)
+    if stack is None:
+        return kp, vp, kp, vp, None
+    layer = stack[1]
+    return kp, vp, kp[layer], vp[layer], jnp.int32(layer)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,KVH,hd,NB,bs,MB", [
-    (3, 8, 2, 64, 16, 128, 4),
-    (2, 4, 4, 128, 8, 256, 2),
-    (1, 16, 2, 80, 12, 64, 6),
-])
-def test_block_paged_decode_attention(B, H, KVH, hd, NB, bs, MB, dtype):
+@pytest.mark.parametrize("B,H,KVH,hd,NB,bs,MB,stack", _pool_cases(
+    (3, 8, 2, 64, 16, 128, 4, None),
+    (2, 4, 4, 128, 8, 256, 2, None),
+    (1, 16, 2, 80, 12, 64, 6, None),
+    (3, 8, 2, 64, 16, 128, 4, (3, 2)),
+    (2, 32, 4, 128, 24, 16, 8, (6, 1)),
+))
+def test_block_paged_decode_attention(B, H, KVH, hd, NB, bs, MB, stack,
+                                      dtype):
     """Pallas block-table kernel vs the jnp gather oracle: per-sequence
-    block tables index a shared [NB, bs, KVH, hd] pool."""
+    block tables index a shared [NB, bs, KVH, hd] pool — or one layer of a
+    stacked [L, NB, bs, KVH, hd] pool, which must equal the oracle on that
+    layer's pool alone."""
     from repro.kernels.paged_attention import block_paged_decode_attention
     q = jnp.asarray(RNG.standard_normal((B, H, hd)), dtype)
-    kp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), dtype)
-    vp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), dtype)
+    kp, vp, k_at, v_at, layer = _pools(NB, bs, KVH, hd, dtype, stack)
     bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB)
                      .astype(np.int32))
     lengths = jnp.asarray(RNG.integers(1, MB * bs + 1, B), jnp.int32)
-    want = ref.block_paged_decode_attention_ref(q, kp, vp, bt, lengths)
-    got = block_paged_decode_attention(q, kp, vp, bt, lengths,
+    want = ref.block_paged_decode_attention_ref(q, k_at, v_at, bt, lengths)
+    got = block_paged_decode_attention(q, kp, vp, bt, lengths, layer,
                                        interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
     # ops export: ref fallback on CPU must agree too
-    got_ops = ops.block_paged_decode_attention(q, kp, vp, bt, lengths)
+    got_ops = ops.block_paged_decode_attention(q, kp, vp, bt, lengths, layer)
     np.testing.assert_allclose(np.asarray(got_ops, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,KVH,hd,NB,bs,MB,Sq", [
-    (2, 4, 2, 64, 12, 64, 4, 32),
-    (1, 8, 4, 128, 8, 128, 2, 48),
-    (3, 4, 1, 80, 20, 32, 6, 16),
-])
-def test_mixed_block_paged_attention(B, H, KVH, hd, NB, bs, MB, Sq, dtype):
+@pytest.mark.parametrize("B,H,KVH,hd,NB,bs,MB,Sq,stack", _pool_cases(
+    (2, 4, 2, 64, 12, 64, 4, 32, None),
+    (1, 8, 4, 128, 8, 128, 2, 48, None),
+    (3, 4, 1, 80, 20, 32, 6, 16, None),
+    (2, 4, 2, 64, 12, 64, 4, 32, (2, 1)),
+    (1, 32, 4, 128, 24, 16, 8, 64, (6, 5)),
+))
+def test_mixed_block_paged_attention(B, H, KVH, hd, NB, bs, MB, Sq, stack,
+                                     dtype):
     """Mixed chunked-prefill/decode kernel vs the jnp gather oracle, with
     per-sequence chunk lengths deliberately unaligned to both the compiled
-    ``Sq`` bucket and the block size.  Only valid chunk rows are compared —
-    padding rows degrade to full-context decode masking by design."""
+    ``Sq`` bucket and the block size, over a single-layer pool or one layer
+    of a stacked pool.  Only valid chunk rows are compared — padding rows
+    degrade to full-context decode masking by design."""
     from repro.kernels.paged_attention import mixed_block_paged_attention
     q = jnp.asarray(RNG.standard_normal((B, Sq, H, hd)), dtype)
-    kp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), dtype)
-    vp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), dtype)
+    kp, vp, k_at, v_at, layer = _pools(NB, bs, KVH, hd, dtype, stack)
     bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB)
                      .astype(np.int32))
     q_lens = RNG.integers(1, Sq + 1, B)
     ctx = np.array([RNG.integers(ql, MB * bs + 1) for ql in q_lens])
     q_lens, ctx = jnp.asarray(q_lens, jnp.int32), jnp.asarray(ctx, jnp.int32)
-    want = ref.mixed_block_paged_attention_ref(q, kp, vp, bt, ctx, q_lens)
-    got = mixed_block_paged_attention(q, kp, vp, bt, ctx, q_lens,
+    want = ref.mixed_block_paged_attention_ref(q, k_at, v_at, bt, ctx, q_lens)
+    got = mixed_block_paged_attention(q, kp, vp, bt, ctx, q_lens, layer,
                                       interpret=True)
-    got_ops = ops.mixed_block_paged_attention(q, kp, vp, bt, ctx, q_lens)
+    got_ops = ops.mixed_block_paged_attention(q, kp, vp, bt, ctx, q_lens,
+                                              layer)
     for b in range(B):
         n = int(q_lens[b])
         np.testing.assert_allclose(np.asarray(got[b, :n], np.float32),
@@ -223,6 +254,45 @@ def test_mixed_block_paged_attention(B, H, KVH, hd, NB, bs, MB, Sq, dtype):
         np.testing.assert_allclose(np.asarray(got_ops[b, :n], np.float32),
                                    np.asarray(want[b, :n], np.float32),
                                    **tol(dtype))
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+@pytest.mark.parametrize("L,layer", [(1, 0), (4, 3)])
+def test_quant_paged_attention_reads_stacked_layer(kind, L, layer):
+    """The int8 kernels over stacked int8 pools [L, NB, bs, KVH, hd] and
+    scale pools [L, NB, bs], read at ``layer``, equal the dequant oracle on
+    that layer's pools alone: entries and scales come from the same layer
+    and block."""
+    from repro.kernels.quant import quantize_rows
+    B, H, KVH, hd, NB, bs, MB, Sq = 2, 8, 4, 64, 12, 16, 4, 8
+    kq, ks = quantize_rows(jnp.asarray(
+        RNG.standard_normal((L, NB, bs, KVH, hd)), jnp.float32), (-2, -1))
+    vq, vs = quantize_rows(jnp.asarray(
+        RNG.standard_normal((L, NB, bs, KVH, hd)), jnp.float32), (-2, -1))
+    at = [a[layer] for a in (kq, ks, vq, vs)]
+    bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB), jnp.int32)
+    ly = jnp.int32(layer)
+    if kind == "decode":
+        q = jnp.asarray(RNG.standard_normal((B, H, hd)), jnp.float32)
+        lengths = jnp.asarray([64, 23], jnp.int32)
+        got = ops.quant_block_paged_decode_attention(
+            q, kq, ks, vq, vs, bt, lengths, ly, impl="kernel")
+        want = ref.quant_block_paged_decode_attention_ref(q, *at, bt, lengths)
+        ref_stacked = ref.quant_block_paged_decode_attention_ref(
+            q, kq, ks, vq, vs, bt, lengths, ly)
+    else:
+        q = jnp.asarray(RNG.standard_normal((B, Sq, H, hd)), jnp.float32)
+        ctx, qlen = (jnp.asarray([40, 9], jnp.int32),
+                     jnp.asarray([8, 1], jnp.int32))
+        got = ops.quant_mixed_block_paged_attention(
+            q, kq, ks, vq, vs, bt, ctx, qlen, ly, impl="kernel")
+        want = ref.quant_mixed_block_paged_attention_ref(q, *at, bt, ctx,
+                                                         qlen)
+        ref_stacked = ref.quant_mixed_block_paged_attention_ref(
+            q, kq, ks, vq, vs, bt, ctx, qlen, ly)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
+    np.testing.assert_array_equal(np.asarray(ref_stacked), np.asarray(want))
 
 
 def test_mixed_sentinel_block_rows_are_inert():
@@ -301,12 +371,15 @@ def test_mixed_bf16_vs_f32_oracle():
 def test_block_paged_decode_remap_invariance():
     """Permuting pool rows + rewriting the tables must not change results —
     the zero-copy-remap guarantee at kernel level (what makes the HMM's
-    commit-time pool growth safe for live sequences)."""
+    commit-time pool growth safe for live sequences).  It holds for the
+    stacked pool the serving step reads by layer: the rows of every layer
+    move together along the block axis (axis 1)."""
     from repro.kernels.paged_attention import block_paged_decode_attention
-    B, H, KVH, hd, NB, bs, MB = 2, 4, 2, 64, 12, 128, 3
+    B, H, KVH, hd, NB, bs, MB, L = 2, 4, 2, 64, 12, 128, 3, 3
     q = jnp.asarray(RNG.standard_normal((B, H, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((NB, bs, KVH, hd)), jnp.float32)
+    kst = jnp.asarray(RNG.standard_normal((L, NB, bs, KVH, hd)), jnp.float32)
+    vst = jnp.asarray(RNG.standard_normal((L, NB, bs, KVH, hd)), jnp.float32)
+    kp, vp = kst[1], vst[1]
     bt = jnp.asarray(RNG.permutation(NB)[:B * MB].reshape(B, MB)
                      .astype(np.int32))
     lengths = jnp.asarray([200, 350], jnp.int32)
@@ -320,6 +393,16 @@ def test_block_paged_decode_remap_invariance():
                                        interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(base),
                                rtol=1e-6, atol=1e-6)
+    for layer in range(L):
+        stacked = block_paged_decode_attention(
+            q, kst, vst, bt, lengths, jnp.int32(layer), interpret=True)
+        moved = block_paged_decode_attention(
+            q, kst[:, jnp.asarray(inv)], vst[:, jnp.asarray(inv)], bt2,
+            lengths, jnp.int32(layer), interpret=True)
+        np.testing.assert_array_equal(np.asarray(moved), np.asarray(stacked))
+        if layer == 1:
+            np.testing.assert_allclose(np.asarray(stacked), np.asarray(base),
+                                       rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [
@@ -389,11 +472,12 @@ def test_kv_cache_write_inplace(B, S, KVH, hd, block):
 def test_paged_attention_runs_per_kv_partition():
     """On a mesh whose 'dp' axis shards the pool, the Mosaic kernel runs
     once per KV partition (``layers.paged_attend``; a Mosaic kernel cannot
-    be partitioned automatically).  A decode batch split across replicas,
-    and one prefill chunk answered by the replica that owns its blocks,
-    both equal the oracle over the whole pool.  A row with a live block
-    outside its home partition comes out NaN, never a wrong block's
-    answer."""
+    be partitioned automatically).  The pools are stacked [L, NB, ...],
+    sharded on the block axis (axis 1), and read at a replicated layer
+    index.  A decode batch split across replicas, and one prefill chunk
+    answered by the replica that owns its blocks, both equal the oracle
+    over that layer's whole pool.  A row with a live block outside its home
+    partition comes out NaN, never a wrong block's answer."""
     from helpers import run_with_devices
     run_with_devices("""
 import functools
@@ -402,36 +486,38 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.distributed.sharding import ParallelCtx
 from repro.kernels import ops, ref
 from repro.models.layers import paged_attend
-n, nbp, bs, KVH, hd, H, bpr, MB, Sq = 4, 6, 8, 2, 64, 4, 2, 3, 8
+n, nbp, bs, KVH, hd, H, bpr, MB, Sq, L = 4, 6, 8, 2, 64, 4, 2, 3, 8, 3
 mesh = Mesh(np.array(jax.devices()[:n]).reshape(n, 1), ("dp", "tp"))
 ctx_ = ParallelCtx(mesh=mesh, ep_axes=("dp", "tp"), tp_axis="tp",
                    dp_axes=("dp",), moe_tp=False)
 rng = np.random.default_rng(0)
 NB = n * nbp
-pool = lambda: jnp.asarray(rng.standard_normal((NB, bs, KVH, hd)),
+pool = lambda: jnp.asarray(rng.standard_normal((L, NB, bs, KVH, hd)),
                            jnp.float32)
-kp, vp = pool(), pool()
-put = lambda x: jax.device_put(x, NamedSharding(mesh, P("dp")))
+kst, vst = pool(), pool()
+layer = jnp.int32(2)
+kp, vp = kst[2], vst[2]
+put = lambda x: jax.device_put(x, NamedSharding(mesh, P(None, "dp")))
 decode = jax.jit(lambda q, k, v, *a: paged_attend(
     ctx_, functools.partial(ops.block_paged_decode_attention, impl="kernel"),
-    q, (k, v), *a))
+    q, (k, v), layer, *a))
 chunk = jax.jit(lambda q, k, v, *a: paged_attend(
     ctx_, functools.partial(ops.mixed_block_paged_attention, impl="kernel"),
-    q, (k, v), *a))
+    q, (k, v), layer, *a))
 lens = rng.integers(1, MB * bs + 1, n * bpr).astype(np.int32)
 bt = np.full((n * bpr, MB), NB, np.int32)          # NB = padding sentinel
 for b in range(n * bpr):                    # slot b -> partition b // bpr
     live = -(-int(lens[b]) // bs)
     bt[b, :live] = rng.permutation(nbp)[:live] + (b // bpr) * nbp
 q = jnp.asarray(rng.standard_normal((n * bpr, H, hd)), jnp.float32)
-got = decode(q, put(kp), put(vp), bt, lens)
+got = decode(q, put(kst), put(vst), bt, lens)
 want = ref.block_paged_decode_attention_ref(q, kp, vp, bt, lens)
 np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
 qc = jnp.asarray(rng.standard_normal((1, Sq, H, hd)), jnp.float32)
 ctx, ql = np.array([20], np.int32), np.array([Sq], np.int32)
 tc = np.full((1, MB), NB, np.int32)
 tc[0] = rng.permutation(nbp)[:MB] + 2 * nbp          # owned by replica 2
-got = chunk(qc, put(kp), put(vp), tc, ctx, ql)
+got = chunk(qc, put(kst), put(vst), tc, ctx, ql)
 want = ref.mixed_block_paged_attention_ref(qc, kp, vp, tc, ctx, ql)
 np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-4)
 # a live block of slot 0 (replica 0) moved to replica 1's partition, and a
@@ -440,8 +526,9 @@ bad = bt.copy()
 bad[0, 0] = nbp
 tbad = tc.copy()
 tbad[0, 1] = 0
-got = np.asarray(decode(q, put(kp), put(vp), bad, lens))
+got = np.asarray(decode(q, put(kst), put(vst), bad, lens))
 assert np.isnan(got[0]).all() and np.isfinite(got[1:]).all()
-assert np.isnan(np.asarray(chunk(qc, put(kp), put(vp), tbad, ctx, ql))).all()
+assert np.isnan(np.asarray(chunk(qc, put(kst), put(vst), tbad, ctx,
+                                 ql))).all()
 print("PARTITION-OK")
 """, ndev=4)

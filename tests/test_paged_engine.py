@@ -227,3 +227,59 @@ srv.hmm.kv_blocks.check_invariants()
 print("LAZY-PREFILL-LRU-OK", sorted(eng._lazy_prefill))
 """, ndev=4)
     assert "LAZY-PREFILL-LRU-OK" in out
+
+
+@pytest.mark.slow
+def test_paged_dense_prefix_layers_match_forward_with_and_without_routing():
+    """A MoE config whose first layer is dense (``first_k_dense=1``) on two
+    replicas, paged KV with chunked prefill: the dense-prefix layer and the
+    scanned MoE layers each write and read their own layer of the one
+    stacked pool.  Every generated token equals the greedy choice of the
+    full-sequence forward pass (teacher-forced on the engine's own tokens),
+    and the routing-telemetry decode, run on every tick, changes no token
+    and counts one row per MoE layer."""
+    out = run_with_devices(PAGED_COMMON + """
+import dataclasses
+import jax, jax.numpy as jnp
+from repro.models import model as M
+
+cfg = dataclasses.replace(MCFG, name="test-moe-dense-prefix", num_layers=3,
+                          first_k_dense=1)
+c2 = ElasticConfig(dp=2, tp=1, devices=(0, 1))
+
+def serve(routing):
+    srv = ElasticServer(cfg, tp=1, batch_per_replica=2, max_len=128,
+                        prefill_buckets=(32,), seed=0, kv_mode="paged",
+                        kv_block_size=16, prefill_chunk=32,
+                        routing_sample_every=routing)
+    srv.boot(c2)
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, 0.1 * i, L, o, prompt=rng.integers(0, 128, L))
+            for i, (L, o) in enumerate([(20, 12), (45, 9), (70, 14),
+                                        (33, 10)])]
+    drive(srv, reqs)
+    srv.hmm.kv_blocks.check_invariants()
+    return srv, {r.rid: srv.engine.generated[r.rid] for r in reqs}, reqs
+
+srv, toks, reqs = serve(0)
+fwd = jax.jit(lambda p, t: M.forward(cfg, p, {"tokens": t}, remat=False)[0])
+for r in reqs:
+    assert len(toks[r.rid]) == r.output_len, r.rid
+    seq = np.concatenate([np.asarray(r.prompt), toks[r.rid][:-1]])
+    logits = np.asarray(fwd(srv.hmm.params,
+                            jnp.asarray(seq[None], jnp.int32)))[0]
+    logits = logits[r.prompt_len - 1:]
+    top2 = np.sort(logits, -1)[:, -2:]
+    # a tie within float noise may go either way; none is that close here
+    assert (top2[:, 1] - top2[:, 0]).min() > 1e-4, r.rid
+    assert (logits.argmax(-1) == np.asarray(toks[r.rid])).all(), r.rid
+
+routed, routed_toks, _ = serve(1)
+assert routed_toks == toks
+stats = routed.engine.routing_stats()
+assert stats["samples"] > 0
+assert stats["counts"].shape == (cfg.num_layers - cfg.first_k_dense,
+                                 cfg.num_experts)
+print("PAGED-DENSE-PREFIX-OK", stats["samples"])
+""", ndev=2)
+    assert "PAGED-DENSE-PREFIX-OK" in out
