@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     cell = spec.load_cell(args.workload)
     conf, serving = cell.config, cell.config["serving"]
-    mcfg = spec.model_config(conf)
+    mcfg = cell.arch.model_config(conf)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     ops.on_cpu = lambda: False       # the described chips are not the CPU

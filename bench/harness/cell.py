@@ -9,7 +9,6 @@ of a run on the CPU that way); the command itself never passes it.
 from __future__ import annotations
 
 import contextlib
-import importlib.util
 import json
 import os
 import shutil
@@ -45,19 +44,6 @@ def process_start() -> float:
         return now - age if 0 <= age < 3600 else now
     except (OSError, ValueError, IndexError):
         return now
-
-
-def plugin(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module."""
-    path = spec.BENCH / kind / f"{name}.py"
-    mod_name = f"bench_{kind}_{name.replace('.', '_')}"
-    if mod_name in sys.modules:
-        return sys.modules[mod_name]
-    s = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(s)
-    s.loader.exec_module(mod)
-    sys.modules[mod_name] = mod
-    return mod
 
 
 def look_for_chip(chips: int):
@@ -134,12 +120,12 @@ class CompileCounter:
 
 
 @contextlib.contextmanager
-def program_weights(conf):
+def program_weights(arch, conf):
     """The program's boot builds its weights with the benchmark's
     generator (one jitted call on the device, from the seed's key)."""
     from repro.models import model as M
     orig = M.init_params
-    M.init_params = weights.program_init(conf)
+    M.init_params = weights.program_init(arch, conf)
     try:
         yield orig
     finally:
@@ -160,7 +146,7 @@ class RunView:
         return [r for r in self.records if r.phase == "window"]
 
     def work(self, name: str):
-        return plugin("work", name)
+        return spec.plugin("work", name)
 
     def trace_busy(self):
         if self._busy is None:
@@ -217,16 +203,16 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              else stats.PEAKS.get(dev.device_kind))
     conf, mix, cp = cell.config, cell.traffic, cell.params
     serving = conf["serving"]
-    mcfg = spec.model_config(conf)
+    mcfg = cell.arch.model_config(conf)
     from repro.core.topology import ElasticConfig
     from repro.models import model as M
-    weights.check_layout(conf, M.init_params, mcfg)
+    weights.check_layout(cell.arch, conf, M.init_params, mcfg)
     # a scale cell boots on its first chips and scales live to all of them
     # at the window's start; its target is compiled in set-up
     scale = cp.get("scale")
     boot_dp = int(scale["from_chips"]) if scale else cell.chips
     srv = serve.build_server(mcfg, serving, weights.fold32(seed))
-    with program_weights(conf):
+    with program_weights(cell.arch, conf):
         srv.boot(ElasticConfig(dp=boot_dp, tp=1,
                                devices=tuple(range(boot_dp))))
     target = None
@@ -319,7 +305,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     names = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for name in names:
-        value = plugin("metrics", name).read(view)
+        value = spec.plugin("metrics", name).read(view)
         if value is not None:
             metrics[name] = {"value": float(value), "unit": cell.units[name]}
         else:

@@ -7,9 +7,11 @@ the requests due in the window, the one that asks for the most tokens
 nothing new, until every sampled request has finished: a minute of
 serving at most, counted from when serving resumes (a traced run stops its
 profiler first and reads the trace only after; ``serve.Driver.drain``).  A
-sampled request that has not finished by then counts as never answered.  Once the server's device state is freed, each
-is run once through the plain reference (``reference.py``) over its prompt
-and the tokens the server produced.  Serving is greedy, so each served
+sampled request that has not finished by then counts as never answered.
+Once the server's device state is freed, each is run once through the
+plain reference (``hidden`` and ``head`` of the configuration's
+architecture file, ``bench/arch/``) over its prompt and the tokens the
+server produced.  Serving is greedy, so each served
 token should be the reference's best or within rounding of it.  At each
 served token the gap is how far its reference logit lies below the
 reference's best logit there.  Read over every served token of the
@@ -41,7 +43,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from harness import reference, weights
+from harness import weights
 
 
 def sample(records, n: int, seed: int) -> List:
@@ -79,7 +81,7 @@ def run(cell, seed: int, records, generated: Dict[int, List[int]],
     "int8"): in place of the served tokens, read the tokens the reference
     computed in that type puts first at the same positions of the same
     sequences (a control, which a sound limit reads as not correct)."""
-    conf, cp, mix = cell.config, cell.params, cell.traffic
+    conf, cp, mix, ref = cell.config, cell.params, cell.traffic, cell.arch
     V = conf["vocab_size"]
     limits = cp["limits"]
     finished = [r for r in records if r.finish is not None]
@@ -102,14 +104,14 @@ def run(cell, seed: int, records, generated: Dict[int, List[int]],
         key = weights.seed_key(seed)
         served = tgt
         if control:
-            x = reference.hidden(conf, key, toks, control)
-            _, first = reference.head(conf, key, x, tgt, control)
+            x = ref.hidden(conf, key, toks, control)
+            _, first = ref.head(conf, key, x, tgt, control)
             del x
             tgt = np.where(on, np.asarray(first), -1).astype(np.int32)
-        x = reference.hidden(conf, key, toks)
+        x = ref.hidden(conf, key, toks)
         for prefix, t in (("", tgt),) + ((("served_", served),)
                                           if control else ()):
-            gap, best = reference.head(conf, key, x, t)
+            gap, best = ref.head(conf, key, x, t)
             gap, best = np.asarray(gap), np.asarray(best)
             read = {"mean_gap": float(gap[on].mean()),
                     "not_best_share": float((best[on] != t[on]).mean()),

@@ -2,32 +2,39 @@
 
 ``BENCHMARK.json`` (at the checkout's root) names each cell's
 configuration and traffic mix; everything else about a cell lives in files
-of its own under ``bench/``:
+of its own under ``bench/``, which the harness finds by name:
 
 * ``configs/<config>.json``  the model as it is run (published keys, the
-  serving shape, what was cut and what is not modelled);
+  serving shape, what was cut and what is not modelled), and ``"arch"``,
+  the name of its architecture file;
+* ``arch/<arch>.py``         the architecture: how the program's
+  ``ModelConfig`` follows from the file, the weights in the program's
+  layout, the plain reference's forward pass and the FLOPs of a token;
 * ``traffic/<mix>.json``     the length distributions of a traffic mix;
 * ``cells/<cell>.json``      the cell's fixed offered rate and the limit of
-  each number its correctness check compares.
+  each number its correctness check compares;
+* ``metrics/<metric>.py``    the reader of one metric;
+* ``work/<kernel>.py``       the FLOPs and bytes of one kernel's calls.
 
-A new cell, mix or configuration is new files plus entries in
-``BENCHMARK.json``; nothing here changes.
+A new configuration brings ``configs/<name>.json`` with its ``"arch"``;
+``arch/<arch>.py`` only where no existing file computes its block;
+``cells/<cell>.json``; ``metrics/`` and ``work/`` files for kernels no
+existing reader counts; and appended ``configs``, ``workloads`` and
+``per_layer`` entries in ``BENCHMARK.json``.  Nothing here changes, and a
+metric without a ``workloads`` list is read in every cell, new ones too.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-
-# what the program runs, whatever a configuration asks: these are fixed in
-# its code (models/layers.py: apply_norm eps, rope_tables base and its
-# interleaved pairs; models/moe.py: route renormalises the top-k weights)
-PROGRAM_FIXED = {"rms_norm_eps": 1e-5, "rope_theta": 10000,
-                 "norm_topk_prob": True, "rope_scaling": None}
 
 
 def _load(path: Path) -> Dict[str, Any]:
@@ -35,11 +42,30 @@ def _load(path: Path) -> Dict[str, Any]:
         return json.load(f)
 
 
+def plugin(kind: str, name: str) -> ModuleType:
+    """``bench/<kind>/<name>.py`` as a module."""
+    path = BENCH / kind / f"{name}.py"
+    mod_name = f"bench_{kind}_{name.replace('.', '_')}"
+    if mod_name in sys.modules:
+        return sys.modules[mod_name]
+    s = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(s)
+    s.loader.exec_module(mod)
+    sys.modules[mod_name] = mod
+    return mod
+
+
+def arch(conf: Dict[str, Any]) -> ModuleType:
+    """The architecture file a configuration names."""
+    return plugin("arch", conf["arch"])
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
     chips: int
     config: Dict[str, Any]       # configs/<config>.json
+    arch: ModuleType             # arch/<config's "arch">.py
     traffic: Dict[str, Any]      # traffic/<mix>.json
     params: Dict[str, Any]       # cells/<cell>.json
     per_layer: tuple             # names of the per-layer metrics it reports
@@ -59,56 +85,17 @@ def load_cell(name: str, benchmark: Path = ROOT / "BENCHMARK.json") -> Cell:
                          f"{sorted(cells)}")
     w = cells[name]
     conf = next(c for c in spec["configs"] if c["name"] == w["config"])
+    config = _load(ROOT / conf["file"])
+    if "arch" not in config:
+        raise SystemExit(f"{conf['file']}: no \"arch\": name the "
+                         "architecture file under bench/arch/ that "
+                         "computes this configuration")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name)]
     per = [m for m in spec["per_layer"] if _applies(m, name)]
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load(ROOT / conf["file"]),
+        name=name, chips=int(w["chips"]), config=config, arch=arch(config),
         traffic=_load(BENCH / "traffic" / f"{w['traffic']}.json"),
         params=_load(BENCH / "cells" / f"{name}.json"),
         per_layer=tuple(m["name"] for m in per),
         end_to_end=tuple(m["name"] for m in e2e),
         units={m["name"]: m["unit"] for m in e2e + per})
-
-
-def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file.
-
-    Widths, depth and vocabulary come from the file; the registry entry
-    named by ``registry`` supplies the architecture family.  A key the
-    program cannot run as the file states raises: the file says what runs.
-    """
-    import dataclasses as dc
-
-    from repro.configs import get_config
-    for key, value in PROGRAM_FIXED.items():
-        if key in conf and conf[key] != value:
-            raise SystemExit(f"{conf['registry']}: {key}={conf[key]!r}, but "
-                             f"the program runs {value!r}")
-    base = get_config(conf["registry"])
-    E = conf.get("num_experts", conf.get("n_routed_experts", 0))
-    k = conf["num_experts_per_tok"]
-    fields = dict(
-        num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"],
-        num_experts=E, top_k=k,
-        moe_d_ff=conf["moe_intermediate_size"],
-        num_shared_experts=conf.get("n_shared_experts", 0),
-        first_k_dense=conf.get("first_k_dense_replace", 0),
-        dtype=conf["dtype"],
-        # dropless routing: every token reaches its experts whatever the
-        # batch (capacity_for gives C = T rows per expert)
-        capacity_factor=E / k)
-    if base.use_mla:
-        fields.update(kv_lora_rank=conf["kv_lora_rank"],
-                      q_lora_rank=conf["q_lora_rank"] or 0,
-                      qk_nope_dim=conf["qk_nope_head_dim"],
-                      qk_rope_dim=conf["qk_rope_head_dim"],
-                      v_head_dim=conf["v_head_dim"], head_dim=0)
-    else:
-        fields.update(head_dim=conf["head_dim"])
-    return dc.replace(base, **fields)

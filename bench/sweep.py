@@ -39,9 +39,9 @@ def main(argv=None) -> int:
     cellmod.compile_cache()
     from repro.core.topology import ElasticConfig
     conf, mix = cell.config, cell.traffic
-    srv = serve.build_server(spec.model_config(conf), conf["serving"],
+    srv = serve.build_server(cell.arch.model_config(conf), conf["serving"],
                              weights.fold32(args.seed))
-    with cellmod.program_weights(conf):
+    with cellmod.program_weights(cell.arch, conf):
         srv.boot(ElasticConfig(dp=cell.chips, tp=1,
                                devices=tuple(range(cell.chips))))
     d = serve.Driver(srv)

@@ -18,8 +18,8 @@ import pytest  # noqa: E402
 from harness import spec  # noqa: E402
 
 GQA = {
-    "registry": "qwen3-30b-a3b", "hidden_size": 128, "head_dim": 32,
-    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "registry": "qwen3-30b-a3b", "arch": "softmax_moe", "hidden_size": 128,
+    "head_dim": 32, "num_attention_heads": 4, "num_key_value_heads": 2,
     "intermediate_size": 256, "moe_intermediate_size": 64,
     "num_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": True,
     "num_hidden_layers": 2, "vocab_size": 512, "rms_norm_eps": 1e-05,
@@ -29,8 +29,8 @@ GQA = {
                 "kv_mode": "paged"}}
 
 MLA = {
-    "registry": "deepseek-v2-lite-16b", "hidden_size": 128,
-    "first_k_dense_replace": 1, "intermediate_size": 256,
+    "registry": "deepseek-v2-lite-16b", "arch": "softmax_moe",
+    "hidden_size": 128, "first_k_dense_replace": 1, "intermediate_size": 256,
     "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 16,
     "qk_rope_head_dim": 16, "v_head_dim": 16, "num_attention_heads": 4,
     "num_key_value_heads": 4, "moe_intermediate_size": 64,
@@ -51,7 +51,8 @@ MIX = {"prompt": {"dist": "lognormal", "median": 40, "sigma": 0.5,
 def small_cell(conf, limit=0.05, rate=4.0):
     """A cell with the metrics of ``qwen3_30b_a3b.chat`` and these sizes."""
     real = spec.load_cell("qwen3_30b_a3b.chat")
-    return spec.Cell(name="small.chat", chips=1, config=conf, traffic=MIX,
+    return spec.Cell(name="small.chat", chips=1, config=conf,
+                     arch=spec.arch(conf), traffic=MIX,
                      params={"rate_rps": rate, "sample_requests": 4,
                              "limits": {"mean_gap": limit}},
                      per_layer=real.per_layer, end_to_end=real.end_to_end,

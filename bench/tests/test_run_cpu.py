@@ -157,7 +157,8 @@ names = ("output_tok_s", "itl_p95_s", "setup_s", "scale_up_s",
          "scale_stall_s", "staging_gbps")
 units = {"output_tok_s": "tokens/s", "itl_p95_s": "s", "setup_s": "s",
          "scale_up_s": "s", "scale_stall_s": "s", "staging_gbps": "GB/s"}
-cell = spec.Cell(name="small.scale", chips=4, config=GQA, traffic=MIX,
+cell = spec.Cell(name="small.scale", chips=4, config=GQA,
+                 arch=spec.arch(GQA), traffic=MIX,
                  params={"rate_rps": 6.0, "sample_requests": 4,
                          "limits": {"mean_gap": 0.05},
                          "scale": {"from_chips": 2, "to_chips": 4}},

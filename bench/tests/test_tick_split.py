@@ -9,7 +9,8 @@ import pytest
 
 from harness import tick_split
 from harness import trace as tm
-from harness.cell import RunView, plugin
+from harness.cell import RunView
+from harness.spec import plugin
 
 DATA = Path(__file__).resolve().parents[1] / "testdata"
 SPANS = DATA / "qwen3_chat_v5e_spans.json"

@@ -98,7 +98,8 @@ def test_decode_step_read_from_the_recorded_trace():
     """The decode executable is the module whose run is last among the
     step-sized runs of most ``srv.tick`` spans (prefill first, then
     decode, in each tick); every run of it in the window counts."""
-    from harness.cell import RunView, plugin
+    from harness.cell import RunView
+    from harness.spec import plugin
     tr = json.loads((DATA / "qwen3_chat_v5e_two_ticks.json").read_text())
     view = RunView(trace=tr, trace_window=tm.window(tr))
     read = plugin("metrics", "decode_step_ms").read

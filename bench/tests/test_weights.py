@@ -10,21 +10,23 @@ from harness import spec, weights
 
 def test_tree_matches_the_programs_layout(conf):
     from repro.models import model as M
-    weights.check_layout(conf, M.init_params, spec.model_config(conf))
+    arch = spec.arch(conf)
+    weights.check_layout(arch, conf, M.init_params, arch.model_config(conf))
 
 
 def test_a_layer_alone_equals_its_slice(conf):
+    arch = spec.arch(conf)
     key = weights.seed_key(2**31 + 5)
-    whole = jax.jit(lambda k: weights.params(conf, k))(key)
-    nk = weights.n_prefix(conf)
+    whole = jax.jit(lambda k: arch.params(conf, k))(key)
+    nk = arch.n_prefix(conf)
     for i in range(conf["num_hidden_layers"] - nk):
-        alone = jax.jit(lambda k, j: weights.layer(conf, k, "block", j))(
+        alone = jax.jit(lambda k, j: arch.layer(conf, k, "block", j))(
             key, jnp.int32(i))
         sl = jax.tree.map(lambda x: x[i], whole["blocks"])
         for a, b in zip(jax.tree.leaves(alone), jax.tree.leaves(sl)):
             assert np.array_equal(np.asarray(a), np.asarray(b))
     if nk:
-        alone = jax.jit(lambda k: weights.layer(conf, k, "prefix", 0))(key)
+        alone = jax.jit(lambda k: arch.layer(conf, k, "prefix", 0))(key)
         for a, b in zip(jax.tree.leaves(alone),
                         jax.tree.leaves(whole["dense_prefix"][0])):
             assert np.array_equal(np.asarray(a), np.asarray(b))

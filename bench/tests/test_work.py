@@ -7,11 +7,16 @@ from pathlib import Path
 import pytest
 
 from harness import serve
-from harness.cell import plugin
+from harness.spec import arch, plugin
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 QWEN = json.loads((CONFIGS / "qwen3_30b_a3b.json").read_text())
-DSV2 = json.loads((CONFIGS / "deepseek_v2_lite.json").read_text())
+# DeepSeek-V2-Lite's published widths (huggingface.co/deepseek-ai/
+# DeepSeek-V2-Lite config.json), 8 of its 27 layers
+DSV2 = {"hidden_size": 2048, "moe_intermediate_size": 1408,
+        "n_routed_experts": 64,
+        "n_shared_experts": 2, "num_experts_per_tok": 6,
+        "first_k_dense_replace": 1, "num_hidden_layers": 8}
 gmm = plugin("work", "paged_gmm")
 attn = plugin("work", "paged_attention")
 step = plugin("work", "model_step")
@@ -75,5 +80,8 @@ def test_model_flops_per_token_hand_count_qwen3():
     attn_proj = 2 * (D * H * hd + 2 * D * KVH * hd + H * hd * D)
     ffn = 2 * D * 128 + 6 * D * 768 * 8
     per_layer = attn_proj + 4 * 1000 * H * hd + ffn
-    assert step.token(QWEN, 1000, True) == pytest.approx(
+    assert arch(QWEN).token(QWEN, 1000, True) == pytest.approx(
         6 * per_layer + 2 * D * V)
+    # a tick's count is its architecture's, token by token
+    t = serve.Tick(0.0, 0.01, decode_ctx=[1000], prefill=[])
+    assert step.tick(QWEN, t) == arch(QWEN).token(QWEN, 1000, True)
